@@ -19,8 +19,8 @@ repro — one driver for both model families:
     or re-materializes the smaller stack with ``--prune-mode shrink``;
   * ``--masked-compute kernel`` additionally routes the masked FFN
     matmuls through the differentiable Pallas ``masked_matmul`` kernel
-    (pruned 128-column blocks skipped on the MXU; set
-    ``REPRO_PALLAS_INTERPRET=1`` on CPU).
+    (pruned 128-column blocks skipped on the MXU; on the CPU backend
+    the kernel runs in Pallas interpret mode).
 
 Examples::
 
@@ -42,6 +42,7 @@ from repro.core.rounds import FederatedTrainer, feddumap_config
 from repro.data.pipeline import build_lm_federated_data
 from repro.data.synthetic import TokenSpec
 from repro.models.lm import LM
+from repro.utils.compile_cache import enable_compile_cache
 
 SCALES = {
     "tiny": dict(num_layers=2, d_model=128, num_heads=4, num_kv_heads=2,
@@ -54,6 +55,7 @@ SCALES = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=20)
     ap.add_argument("--scale", default="tiny", choices=list(SCALES))

@@ -10,9 +10,11 @@ import argparse
 from pathlib import Path
 
 import benchmarks.paper_experiments as PE
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--algo", default="feddumap",
                     choices=["fedavg", "feddu", "feddum", "fedap", "fedduap",

@@ -21,9 +21,11 @@ from repro.data import build_federated_data
 from repro.data.synthetic import SyntheticSpec
 from repro.models import SimpleCNN
 from repro.utils import tree_size
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     spec = SyntheticSpec(num_classes=10, image_shape=(10, 10, 3),
                          train_size=5200, test_size=800, noise_scale=0.5)
     data = build_federated_data(num_clients=20, server_fraction=0.08,

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
 from repro.models.api import build_model
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def serve_continuous(cfg, args):
@@ -138,6 +139,7 @@ def serve_lockstep(cfg, args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-405b", choices=list(ARCH_NAMES))
     ap.add_argument("--requests", type=int, default=8,

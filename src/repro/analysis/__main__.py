@@ -20,7 +20,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get(
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8").strip()
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 _REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
 

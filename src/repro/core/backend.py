@@ -166,7 +166,8 @@ def shrink_params_for(model, params, kept):
 
 
 def build_chunk(eng: EngineConfig, grad_fn, la_fn, sample_kw: dict, *,
-                prefetch: bool = True, constrain=None):
+                prefetch: bool = True, constrain=None, client_map=None,
+                server_map=None):
     """``chunk(state, key, data_dev, length) -> (state, key, mets)`` — one
     scan over `round_core` with device-side sampling.  ``mets`` is a dict
     of per-round stacked metrics: ``{"tau_eff": [length], "health":
@@ -175,7 +176,8 @@ def build_chunk(eng: EngineConfig, grad_fn, la_fn, sample_kw: dict, *,
     mode, so guard configs compile zero extra programs).
 
     ``constrain`` (MeshBackend) maps the sampled batch through sharding
-    constraints so the client axis partitions over the mesh.
+    constraints so the client axis partitions over the mesh;
+    ``client_map``/``server_map`` are passed to ``engine.round_core``.
 
     ``prefetch=True`` double-buffers the sampling: the prologue draws round
     0's batch, and every scan iteration gathers round t+1's batch BEFORE
@@ -196,12 +198,15 @@ def build_chunk(eng: EngineConfig, grad_fn, la_fn, sample_kw: dict, *,
     def _mets(metrics):
         return {"tau_eff": metrics["tau_eff"], "health": metrics["health"]}
 
+    maps = dict(client_map=client_map, server_map=server_map)
+
     def serial_chunk(state, key, data_dev, length):
         def body(carry, _):
             st, k = carry
             k, sub = jax.random.split(k)
             batch = sample(sub, data_dev)
-            st, metrics = engine.round_core(eng, grad_fn, la_fn, st, batch)
+            st, metrics = engine.round_core(eng, grad_fn, la_fn, st, batch,
+                                             **maps)
             return (st, k), _mets(metrics)
 
         (state, key), mets = jax.lax.scan(body, (state, key), None,
@@ -224,7 +229,8 @@ def build_chunk(eng: EngineConfig, grad_fn, la_fn, sample_kw: dict, *,
             st, _, k, batch = carry
             k_next, sub = jax.random.split(k)
             nb = sample(sub, data_dev)          # round t+1, drawn during t
-            st, metrics = engine.round_core(eng, grad_fn, la_fn, st, batch)
+            st, metrics = engine.round_core(eng, grad_fn, la_fn, st, batch,
+                                             **maps)
             return (st, k, k_next, nb), _mets(metrics)
 
         (state, key, _, _), mets = jax.lax.scan(
@@ -659,10 +665,31 @@ class MeshBackend(_EngineBackend):
 
             chunk = build_chunk(self.eng, grad_fn, la_fn, self.sample_kw,
                                 prefetch=self.cfg.prefetch_sampling,
-                                constrain=constrain)
+                                constrain=constrain, **self._kernel_maps())
             self._chunk = jax.jit(chunk, static_argnames=("length",),
                                   donate_argnums=(0,))
         return self._chunk
+
+    def _kernel_maps(self) -> dict:
+        """Kernel mode: GSPMD cannot partition a Mosaic kernel, so local
+        training runs under a ``shard_map`` over the whole mesh (each
+        device vmaps its own clients; replicated when the clients do not
+        divide), and the FedDU server scan runs replicated under one too.
+        The FedAvg reduction around them stays GSPMD-partitioned."""
+        if not self._kernel_masks:
+            return {}
+        from jax.sharding import PartitionSpec as P
+
+        size = self.plan.axis_size(self.plan.client_axes)
+        cspec = (P(self.plan.client_axes)
+                 if self.cfg.clients_per_round % size == 0 else P())
+
+        def manual(f, spec):
+            return jax.shard_map(f, mesh=self.mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False)
+
+        return {"client_map": lambda f: manual(jax.vmap(f), cspec),
+                "server_map": lambda f: manual(f, P())}
 
     def _eval_program(self):
         """The batch-sharded eval program — built WITHOUT lowering the

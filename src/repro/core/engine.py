@@ -329,8 +329,16 @@ def local_train(cfg: EngineConfig, grad_fn: Callable, params: Any, m0: Any,
 
 
 def round_core(cfg: EngineConfig, grad_fn: Callable, loss_and_acc_fn: Callable,
-               state: dict, batch: dict) -> tuple[dict, dict]:
+               state: dict, batch: dict, *, client_map: Callable | None = None,
+               server_map: Callable | None = None) -> tuple[dict, dict]:
     """One full federated round (paper steps 2-5), pure and scan-safe.
+
+    ``client_map`` maps the per-client local training over the leading
+    client axis (default ``jax.vmap``); ``server_map`` wraps the FedDU
+    server scan ``(w_half, server_batch) -> (w_end, acc)`` (default: run
+    it as is).  The mesh backend's kernel mode passes ``shard_map``
+    wrappers here: a Mosaic kernel cannot be partitioned by GSPMD, so each
+    device must run it on its own shard.
 
     batch:
       client    pytree, leading dims [C, steps, ...] (per-client batches)
@@ -402,6 +410,7 @@ def round_core(cfg: EngineConfig, grad_fn: Callable, loss_and_acc_fn: Callable,
         m0 = _m(state["global_m"])             # FedDA: broadcast momentum
     else:
         m0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+    client_map = client_map or jax.vmap
     if cfg.algorithm == "feddyn":
         if "sel" not in batch:
             raise ValueError(
@@ -410,16 +419,16 @@ def round_core(cfg: EngineConfig, grad_fn: Callable, loss_and_acc_fn: Callable,
                 "sample_round_batches emits it")
         h_all = state["client_state"]["per_client"]["h"]
         h_sel = _m(jax.tree.map(lambda x: x[batch["sel"]], h_all))
-        locals_, local_ms = jax.vmap(
+        locals_, local_ms = client_map(
             lambda b, hk: local_train(cfg, grad_fn, params, m0, b, lr,
                                       anchor=params, h=hk))(
                 batch["client"], h_sel)
     elif cfg.algorithm == "fedprox":
-        locals_, local_ms = jax.vmap(
+        locals_, local_ms = client_map(
             lambda b: local_train(cfg, grad_fn, params, m0, b, lr,
                                   anchor=params))(batch["client"])
     else:
-        locals_, local_ms = jax.vmap(
+        locals_, local_ms = client_map(
             lambda b: local_train(cfg, grad_fn, params, m0, b,
                                   lr))(batch["client"])
 
@@ -534,8 +543,13 @@ def round_core(cfg: EngineConfig, grad_fn: Callable, loss_and_acc_fn: Callable,
             p = jax.tree.map(lambda pi, gi: (pi - lr * gi).astype(pi.dtype), p, g)
             return (p, acc0, jnp.zeros((), bool)), None
 
-        (w_end, acc, _), _ = jax.lax.scan(
-            sstep, (w_half, jnp.zeros(()), jnp.ones((), bool)), batch["server"])
+        def server_scan(w, server):
+            (w_end, acc, _), _ = jax.lax.scan(
+                sstep, (w, jnp.zeros(()), jnp.ones((), bool)), server)
+            return w_end, acc
+
+        w_end, acc = (server_map or (lambda f: f))(server_scan)(
+            w_half, batch["server"])
         # Formula 6 via the telescoping identity: mean path gradient.
         g0 = jax.tree.map(
             lambda a, b_: (a.astype(jnp.float32) - b_.astype(jnp.float32))

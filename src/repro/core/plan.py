@@ -386,6 +386,13 @@ class RunResult:
                     "kept_counts",
                     {k: int(np.asarray(v).shape[-1]) for k, v in kept.items()}),
             })
+        # npz holds neither empty subtrees (a param-free layer, e.g. a
+        # non-parametric norm) nor dtypes beyond numpy's own (bfloat16
+        # comes back as raw 2-byte voids): the loader restores both by name
+        meta["empty"] = sorted(k for k, v in arrays.items() if v is None)
+        arrays = {k: v for k, v in arrays.items() if v is not None}
+        meta["dtypes"] = {k: str(np.asarray(v).dtype)
+                          for k, v in arrays.items()}
         tmp = out / f".arrays.npz.tmp-{os.getpid()}"
         with open(tmp, "wb") as f:
             np.savez(f, **{k: np.asarray(v) for k, v in arrays.items()})
@@ -403,9 +410,12 @@ class RunResult:
 
 def _flatten_arrays(tree, prefix: str = "") -> dict:
     """Nested dicts of arrays -> flat {'a/b/c': leaf}.  Keys must be
-    '/'-free strings (true for every model param tree in this repo)."""
+    '/'-free strings (true for every model param tree in this repo).  An
+    empty dict below the root flattens to a None leaf."""
     flat: dict = {}
     if isinstance(tree, dict):
+        if not tree and prefix:
+            return {prefix[:-1]: None}
         for k, v in tree.items():
             k = str(k)
             if "/" in k:
@@ -462,6 +472,7 @@ def load_artifact(path) -> dict:
     import pathlib
     import zipfile
 
+    import jax.numpy as jnp
     import numpy as np
 
     p = pathlib.Path(path)
@@ -480,9 +491,14 @@ def load_artifact(path) -> dict:
         raise CheckpointError(
             f"{p}: partial checkpoint (meta.json present but arrays.npz "
             f"missing — interrupted or incomplete save)")
+    dtypes = meta.get("dtypes", {})
     try:
         with np.load(p / "arrays.npz") as z:
-            tree = _unflatten_arrays({k: z[k] for k in z.files})
+            flat = {k: (z[k].view(jnp.dtype(dtypes[k]))
+                        if k in dtypes and z[k].dtype.kind == "V" else z[k])
+                    for k in z.files}
+        tree = _unflatten_arrays(
+            {**flat, **{k: {} for k in meta.get("empty", [])}})
     except (zipfile.BadZipFile, OSError, ValueError) as e:
         raise CheckpointError(f"{p}: corrupted arrays.npz ({e})") from e
     from repro.configs.base import ModelConfig

@@ -150,13 +150,21 @@ def fisher_spectrum(
     ``n - n_valid`` zero eigenvalues (zero rows/columns), which
     :func:`expected_rate_from_spectrum` masks out via its ``valid=``
     argument.
+
+    The Gram matrix is accumulated leaf by leaf (``sum_leaf g g^T``), so
+    no concatenated [n, P] copy of the per-sample gradients is ever
+    materialized — at published LM widths that copy alone would be
+    n x P x 4 bytes on top of the gradients themselves.
     """
-    g = per_sample_grad_fn(params, probe_batch)
-    flat = jnp.concatenate(
-        [x.reshape(x.shape[0], -1).astype(jnp.float32) for x in jax.tree.leaves(g)], axis=1
-    )
-    n = flat.shape[0] if n_valid is None else n_valid
-    gram = flat @ flat.T / n                      # [n, n], same nonzero spectrum
+    leaves = [x.reshape(x.shape[0], -1)
+              for x in jax.tree.leaves(per_sample_grad_fn(params, probe_batch))]
+    rows = leaves[0].shape[0]
+    gram = jnp.zeros((rows, rows), jnp.float32)
+    for x in leaves:
+        x = x.astype(jnp.float32)
+        gram = gram + x @ x.T
+    n = rows if n_valid is None else n_valid
+    gram = gram / n                               # [n, n], same nonzero spectrum
     eigs = jnp.linalg.eigvalsh(gram)              # ascending
     return jnp.clip(eigs, 0.0, None)
 

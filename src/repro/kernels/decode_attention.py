@@ -41,7 +41,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     # slots are valid (later slots belong to a PREVIOUS occupant of the
     # decode slot, or were never written).
     pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-    valid = pos < len_ref[0]                         # [1, bk]
+    valid = pos < len_ref[pl.program_id(0)]          # [1, bk]
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
@@ -107,23 +107,31 @@ def decode_attention(q, k, v, lengths=None, *, block_k: int = 512,
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                num_kv_blocks=nk, block_k=block_k)
 
+    # ``lens`` is a scalar-prefetch operand (whole in SMEM, read by program
+    # id): the TPU lowering refuses a rank-1 (1,) VMEM block.
     out = pl.pallas_call(
         kernel,
-        grid=(b, kvh, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b_, j_, k_: (b_,)),
-            pl.BlockSpec((1, 1, g, hd), lambda b_, j_, k_: (b_, j_, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b_, j_, k_: (b_, j_, k_, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b_, j_, k_: (b_, j_, k_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b_, j_, k_: (b_, j_, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kvh, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, g, hd),
+                             lambda b_, j_, k_, _: (b_, j_, 0, 0)),
+                pl.BlockSpec((1, 1, block_k, hd),
+                             lambda b_, j_, k_, _: (b_, j_, k_, 0)),
+                pl.BlockSpec((1, 1, block_k, hd),
+                             lambda b_, j_, k_, _: (b_, j_, k_, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, g, hd),
+                                   lambda b_, j_, k_, _: (b_, j_, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, hd), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
         interpret=interpret,
+        name="decode_attention",
     )(lens, qt, kt, vt)
     # [B, KV, G, hd] -> [B, 1, H, hd] with h = g_idx * KV + kv
     return out.transpose(0, 2, 1, 3).reshape(b, 1, h, hd)

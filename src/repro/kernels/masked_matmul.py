@@ -40,11 +40,11 @@ from jax.experimental.pallas import tpu as pltpu
 # kernels
 # ---------------------------------------------------------------------------
 
-def _masked_mm_kernel(x_ref, w_ref, mask_ref, o_ref, acc_scr, *, nk: int):
+def _masked_mm_kernel(mask_ref, x_ref, w_ref, o_ref, acc_scr, *, nk: int):
     """Forward: o[i, j] = sum_k x[i, k] @ w[k, j], skipped when block j is
     pruned (grid (M/bm, N/bn, K/bk), K innermost)."""
     ki = pl.program_id(2)
-    keep = mask_ref[0] > 0
+    keep = mask_ref[pl.program_id(1)] > 0
 
     @pl.when(ki == 0)
     def _init():
@@ -61,13 +61,13 @@ def _masked_mm_kernel(x_ref, w_ref, mask_ref, o_ref, acc_scr, *, nk: int):
         o_ref[...] = jnp.where(keep, acc_scr[...], 0.0).astype(o_ref.dtype)
 
 
-def _masked_dx_kernel(dy_ref, w_ref, mask_ref, dx_ref, acc_scr, *, nn: int):
+def _masked_dx_kernel(mask_ref, dy_ref, w_ref, dx_ref, acc_scr, *, nn: int):
     """Backward-x: dx[i, j] = sum_n dy[i, n] @ w.T[n, j] with pruned ROW
     blocks of ``w.T`` (= pruned column blocks n of ``w``) skipped
     (grid (M/bm, K/bk, N/bn), N innermost).  Exact: the forward zeroed the
     pruned output columns, so their cotangent never contributes."""
     ni = pl.program_id(2)
-    keep = mask_ref[0] > 0
+    keep = mask_ref[ni] > 0
 
     @pl.when(ni == 0)
     def _init():
@@ -86,12 +86,12 @@ def _masked_dx_kernel(dy_ref, w_ref, mask_ref, dx_ref, acc_scr, *, nn: int):
         dx_ref[...] = acc_scr[...].astype(dx_ref.dtype)
 
 
-def _masked_dw_kernel(x_ref, dy_ref, mask_ref, dw_ref, acc_scr, *, nm: int):
+def _masked_dw_kernel(mask_ref, x_ref, dy_ref, dw_ref, acc_scr, *, nm: int):
     """Backward-w: dw[i, j] = sum_m x.T[i, m] @ dy[m, j] with pruned column
     blocks j skipped and their outputs written as EXACT zeros
     (grid (K/bk, N/bn, M/bm), M innermost)."""
     mi = pl.program_id(2)
-    keep = mask_ref[0] > 0
+    keep = mask_ref[pl.program_id(1)] > 0
 
     @pl.when(mi == 0)
     def _init():
@@ -112,26 +112,40 @@ def _masked_dw_kernel(x_ref, dy_ref, mask_ref, dw_ref, acc_scr, *, nm: int):
 
 # ---------------------------------------------------------------------------
 # pallas_call wrappers (blocks = (block_m, block_n, block_k, interpret))
+#
+# The block mask is a scalar-prefetch operand: it lands in SMEM whole, and
+# each grid step reads its own entry by program id.  (A rank-1 (1,) VMEM
+# block is refused by the TPU lowering, which tiles rank-1 blocks in 128s.)
 # ---------------------------------------------------------------------------
+
+def _call(kernel, name, grid, in_specs, out_spec, out_shape, acc_shape,
+          interpret, block_mask, *operands):
+    keep = (block_mask > 0).astype(jnp.int32)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_spec,
+            scratch_shapes=[pltpu.VMEM(acc_shape, jnp.float32)]),
+        out_shape=out_shape,
+        interpret=interpret,
+        name=name,
+    )(keep, *operands)
+
 
 def _fwd_call(blocks, x, w, block_mask):
     bm, bn, bk, interpret = blocks
     m, kdim = x.shape
     n = w.shape[1]
     nk = kdim // bk
-    return pl.pallas_call(
-        functools.partial(_masked_mm_kernel, nk=nk),
-        grid=(m // bm, n // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1,), lambda i, j, k: (j,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, w, block_mask)
+    return _call(
+        functools.partial(_masked_mm_kernel, nk=nk), "masked_matmul_fwd",
+        (m // bm, n // bn, nk),
+        [pl.BlockSpec((bm, bk), lambda i, j, k, _: (i, k)),
+         pl.BlockSpec((bk, bn), lambda i, j, k, _: (k, j))],
+        pl.BlockSpec((bm, bn), lambda i, j, k, _: (i, j)),
+        jax.ShapeDtypeStruct((m, n), x.dtype), (bm, bn), interpret,
+        block_mask, x, w)
 
 
 def _dx_call(blocks, dy, w, block_mask):
@@ -139,19 +153,14 @@ def _dx_call(blocks, dy, w, block_mask):
     m, n = dy.shape
     kdim = w.shape[0]
     nn = n // bn
-    return pl.pallas_call(
-        functools.partial(_masked_dx_kernel, nn=nn),
-        grid=(m // bm, kdim // bk, nn),
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (j, k)),
-            pl.BlockSpec((1,), lambda i, j, k: (k,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bk), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, kdim), dy.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
-        interpret=interpret,
-    )(dy, w, block_mask)
+    return _call(
+        functools.partial(_masked_dx_kernel, nn=nn), "masked_matmul_dx",
+        (m // bm, kdim // bk, nn),
+        [pl.BlockSpec((bm, bn), lambda i, j, k, _: (i, k)),
+         pl.BlockSpec((bk, bn), lambda i, j, k, _: (j, k))],
+        pl.BlockSpec((bm, bk), lambda i, j, k, _: (i, j)),
+        jax.ShapeDtypeStruct((m, kdim), dy.dtype), (bm, bk), interpret,
+        block_mask, dy, w)
 
 
 def _dw_call(blocks, x, dy, block_mask):
@@ -159,19 +168,14 @@ def _dw_call(blocks, x, dy, block_mask):
     m, kdim = x.shape
     n = dy.shape[1]
     nm = m // bm
-    return pl.pallas_call(
-        functools.partial(_masked_dw_kernel, nm=nm),
-        grid=(kdim // bk, n // bn, nm),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (k, i)),
-            pl.BlockSpec((bm, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1,), lambda i, j, k: (j,)),
-        ],
-        out_specs=pl.BlockSpec((bk, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((kdim, n), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, dy, block_mask)
+    return _call(
+        functools.partial(_masked_dw_kernel, nm=nm), "masked_matmul_dw",
+        (kdim // bk, n // bn, nm),
+        [pl.BlockSpec((bm, bk), lambda i, j, k, _: (k, i)),
+         pl.BlockSpec((bm, bn), lambda i, j, k, _: (k, j))],
+        pl.BlockSpec((bk, bn), lambda i, j, k, _: (i, j)),
+        jax.ShapeDtypeStruct((kdim, n), x.dtype), (bk, bn), interpret,
+        block_mask, x, dy)
 
 
 # ---------------------------------------------------------------------------

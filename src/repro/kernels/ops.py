@@ -1,13 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On a real TPU runtime these dispatch to the compiled kernels; in this
-container (CPU) they run in interpret mode when ``REPRO_PALLAS_INTERPRET``
-is set (the tests set it), and the model layers only route here when
-``attn_impl='pallas'`` is requested.
+The platform alone picks the mode: on a TPU the kernels compile for the
+chip, on the CPU backend they run in Pallas interpret mode.  The model
+layers only route here when ``attn_impl='pallas'`` or masked compute is
+requested.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +18,7 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return True
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, causal=True, window=None,

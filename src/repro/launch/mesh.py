@@ -11,12 +11,19 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the engine's gathers and einsums rely on GSPMD propagation,
+    # which explicit-sharding axes (the make_mesh default) would refuse.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1):
@@ -28,4 +35,4 @@ def make_host_mesh(*, data: int | None = None, model: int = 1):
     with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``."""
     n = len(jax.devices())
     data = data or (n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))

@@ -555,7 +555,9 @@ def masked_dense(x, w, mask, b=None, *, block: int = 128):
         y = x @ w
     if b is not None:
         y = y + b
-    return y * mask
+    # the 0/1 mask is f32; multiplying in y's dtype keeps bf16 activations
+    # bf16 (promotion would change the layer scan's carry dtype)
+    return y * mask.astype(y.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -169,7 +169,7 @@ class NaNLogits:
     def apply_logits(self, logits, state):
         import jax.numpy as jnp
 
-        hit = ((jnp.arange(logits.shape[0]) == self.slot)
+        hit = ((state["slot"] == self.slot)
                & (state["n_out"] == self.n_out) & state["active"])
         return jnp.where(hit[:, None, None], jnp.float32(jnp.nan),
                          logits.astype(jnp.float32)).astype(logits.dtype)

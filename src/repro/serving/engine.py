@@ -169,8 +169,21 @@ class DecodeEngine:
         self._params = self._place(params, batched=False)
         if masks is not None:
             self._masks = self._place(masks, batched=False)
+        wave, logits = self._wave_fn, self._logits_fn
+        if mesh is not None:
+            # GSPMD cannot partition the Mosaic kernels (flash-decode, the
+            # masked FFN): each device steps its own slots in a shard_map
+            # over the whole mesh — the step is slot-parallel throughout
+            from jax.sharding import PartitionSpec as P
+
+            specs = (P(), self._state_specs(self._init_state()))
+            wave = jax.shard_map(wave, mesh=mesh, in_specs=specs,
+                                 out_specs=specs[1], check_vma=False)
+            logits = jax.shard_map(logits, mesh=mesh, in_specs=specs,
+                                   out_specs=P(mesh_axis), check_vma=False)
         self._admit = jax.jit(self._admit_fn, donate_argnums=(0,))
-        self._wave = jax.jit(self._wave_fn, donate_argnums=(1,))
+        self._wave = jax.jit(wave, donate_argnums=(1,))
+        self._logits = jax.jit(logits)
         self._state = self._place_state(self._init_state())
         self._occupants: list[Optional[tuple[int, np.ndarray]]] = \
             [None] * self.cfg.slots
@@ -192,38 +205,57 @@ class DecodeEngine:
             "n_out": jnp.zeros((c.slots,), jnp.int32),
             "out": jnp.zeros((c.slots, c.max_new_tokens), jnp.int32),
             "error": jnp.zeros((c.slots,), bool),
+            # global slot ids: under a mesh each device sees only its own
+            "slot": jnp.arange(c.slots, dtype=jnp.int32),
         }
 
-    def _place(self, tree, *, batched: bool, cache: bool = False):
-        """device_put with the mesh sharding (replicated when
-        ``batched=False``); identity on a mesh-less engine."""
-        if self._mesh is None:
-            return tree
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    def _specs(self, tree, *, batched: bool, cache: bool = False):
+        """PartitionSpecs over the mesh: replicated when ``batched=False``,
+        else the slot axis over ``mesh_axis``."""
+        from jax.sharding import PartitionSpec as P
 
         ax = self._mesh_axis
 
-        def put(leaf):
-            nd = jnp.ndim(leaf)
+        def spec(leaf):
             if not batched:
-                spec = P()
-            elif cache and nd > 1:
+                return P()
+            if cache and jnp.ndim(leaf) > 1:
                 # scanned KV stacks [L, slots, S, KV, hd]: batch is axis 1
-                spec = P(None, ax)
-            else:
-                spec = P(ax)
-            return jax.device_put(leaf, NamedSharding(self._mesh, spec))
+                return P(None, ax)
+            return P(ax)
 
-        return jax.tree.map(put, tree)
+        return jax.tree.map(spec, tree)
+
+    def _state_specs(self, state: dict) -> dict:
+        specs = {k: self._specs(v, batched=True)
+                 for k, v in state.items() if k != "cache"}
+        specs["cache"] = self._specs(state["cache"], batched=True,
+                                     cache=True)
+        return specs
+
+    def _place(self, tree, *, batched: bool):
+        """device_put with the mesh sharding (replicated when
+        ``batched=False``); the default device on a mesh-less engine, so
+        host params (a loaded checkpoint) are uploaded once, not per
+        wave."""
+        if self._mesh is None:
+            return jax.device_put(tree)
+        from jax.sharding import NamedSharding
+
+        return jax.tree.map(
+            lambda leaf, spec: jax.device_put(leaf,
+                                              NamedSharding(self._mesh, spec)),
+            tree, self._specs(tree, batched=batched))
 
     def _place_state(self, state: dict) -> dict:
         if self._mesh is None:
             return state
-        placed = {k: self._place(v, batched=True)
-                  for k, v in state.items() if k != "cache"}
-        placed["cache"] = self._place(state["cache"], batched=True,
-                                      cache=True)
-        return placed
+        from jax.sharding import NamedSharding
+
+        return jax.tree.map(
+            lambda leaf, spec: jax.device_put(leaf,
+                                              NamedSharding(self._mesh, spec)),
+            state, self._state_specs(state))
 
     # -- the two compiled programs ---------------------------------------
     def _admit_fn(self, state, slot, prompt, plen):
@@ -277,7 +309,7 @@ class DecodeEngine:
         # a step that consumed the prompt's last token (or any later one)
         # emits a generated token
         emitted = live & (consumed >= state["prompt_len"])
-        row = jnp.arange(c.slots)
+        row = jnp.arange(active.shape[0])      # this device's slots
         pos = jnp.clip(state["n_out"], 0, c.max_new_tokens - 1)
         out = state["out"].at[row, pos].set(
             jnp.where(emitted, sampled, state["out"][row, pos]))
@@ -296,7 +328,14 @@ class DecodeEngine:
             "n_out": n_out,
             "out": out,
             "error": state["error"] | bad,
+            "slot": state["slot"],
         }
+
+    def _logits_fn(self, params, state):
+        logits, _ = self.model.decode_step(
+            params, state["cache"], {"tokens": state["last_tok"][:, None]},
+            masks=self._masks)
+        return logits[:, 0]
 
     def _wave_fn(self, params, state):
         def body(st, _):
@@ -381,6 +420,13 @@ class DecodeEngine:
         return sorted(done, key=lambda comp: comp.uid)
 
     # -- introspection -----------------------------------------------------
+    def next_logits(self) -> np.ndarray:
+        """[slots, vocab] f32 logits of the next decode step of every slot,
+        without advancing the state — for checking one servable or
+        placement against another on identical (e.g. mid-prefill) state."""
+        return np.asarray(self._logits(self._params, self._state)
+                          .astype(jnp.float32))
+
     def lower_wave(self):
         """AOT-lower the wave program against the current state — the
         analysis hook :mod:`repro.analysis.hlo_lint` uses to inspect the

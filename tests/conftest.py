@@ -6,8 +6,6 @@ import sys
 # from the repo root (tests usually run with only PYTHONPATH=src).
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# Kernel tests run the TPU kernels in interpret mode on CPU.
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 # Keep tests on the single real device (the dry-run sets 512 host devices
 # ONLY inside repro.launch.dryrun, never here).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core import (
     FedAPConfig,
@@ -619,7 +619,8 @@ class TestWithMasksShardedRoundTrip:
 
         # every device on the MODEL axis: the w1/w2 hidden dim genuinely
         # shards (8-way under the CI job), clients are explicit batch rows
-        mesh = jax.make_mesh((1, N_DEV), ("data", "model"))
+        mesh = jax.make_mesh((1, N_DEV), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         plan = MeshPlan(mesh=mesh, multi_pod=False, client_axes=(),
                         fsdp_axes=(), tp_axes=("model",), batch_axes=("data",),
                         num_clients=1)

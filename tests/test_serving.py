@@ -379,3 +379,56 @@ class TestCheckpoint:
         res = masked_run_result(zeroed, kept, fmasks)
         sv = load_servable(res, "auto", model_config=CFG)
         assert sv.mode == "masked" and sv.masks is not None
+
+    def test_bf16_params_and_param_free_layers_round_trip(self, tmp_path):
+        """OLMo's shape of checkpoint: bfloat16 params (npz alone returns
+        them as raw 2-byte voids) and a non-parametric norm (an empty
+        subtree npz alone drops) both come back as saved."""
+        cfg = dataclasses.replace(CFG, norm="nonparam",
+                                  param_dtype="bfloat16")
+        model = LM(cfg)
+        params = model.init(jax.random.key(4))
+        assert params["layers"]["norm_a"] == {}
+        RunResult(params=params, history={}, artifacts={}, state={}).save(
+            tmp_path / "ckpt", model_config=cfg)
+        art = load_artifact(tmp_path / "ckpt")
+        assert (jax.tree.structure(art["params"])
+                == jax.tree.structure(params))
+        for g, w in zip(jax.tree.leaves(art["params"]),
+                        jax.tree.leaves(params)):
+            assert g.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(g, np.asarray(w))
+        sv = load_servable(tmp_path / "ckpt")
+        logits, _ = sv.model.decode_step(
+            sv.params, sv.model.init_cache(1, 8),
+            {"tokens": jnp.zeros((1, 1), jnp.int32)})
+        assert logits.dtype == jnp.bfloat16
+
+
+class TestNextLogits:
+    def test_next_logits_is_the_next_step_and_does_not_advance(self, world):
+        """``next_logits`` mid-prefill equals the naive decode_step's
+        logits after the same prompt prefix, and leaves the state (and
+        hence the completions) untouched."""
+        model, params, _, _, _, _, _ = world
+        scfg = ServeConfig(slots=2, cache_len=8, max_prompt=6,
+                           max_new_tokens=2, steps_per_wave=3)
+        prompts = [np.arange(1, 7, dtype=np.int32),
+                   np.arange(20, 26, dtype=np.int32)]
+        eng = DecodeEngine(model, params, scfg)
+        for p in prompts:
+            eng.submit(p)
+        eng.step_wave()                  # 3 of 6 prompt tokens consumed
+        got = eng.next_logits()
+        np.testing.assert_array_equal(got, eng.next_logits())
+        cache = model.init_cache(2, scfg.cache_len)
+        step = jax.jit(lambda c, t: model.decode_step(params, c,
+                                                      {"tokens": t}))
+        for t in range(4):               # 3 consumed + the next one
+            want, cache = step(cache, jnp.asarray(
+                [[prompts[0][t]], [prompts[1][t]]], jnp.int32))
+        np.testing.assert_allclose(got, np.asarray(want[:, 0]), atol=1e-5)
+        done = eng.run()
+        ref = DecodeEngine(model, params, scfg).run(prompts)
+        for a, b in zip(done, ref):
+            np.testing.assert_array_equal(a.tokens, b.tokens)
