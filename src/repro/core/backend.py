@@ -50,6 +50,7 @@ tests/test_plan.py), so prefetching is purely a scheduling change.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import inspect
 import time
 from typing import Any, Protocol, runtime_checkable
@@ -165,6 +166,12 @@ def shrink_params_for(model, params, kept):
     return pruning.shrink_params(params, model.prune_spec(params), kept)
 
 
+def _span(name: str):
+    """Decorate a backend entry point with a host span on the profiler's
+    clock (``name`` as ``jax.profiler`` shows it)."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
+
+
 def build_chunk(eng: EngineConfig, grad_fn, la_fn, sample_kw: dict, *,
                 prefetch: bool = True, constrain=None, client_map=None,
                 server_map=None):
@@ -191,9 +198,14 @@ def build_chunk(eng: EngineConfig, grad_fn, la_fn, sample_kw: dict, *,
     chunk's first draw, recomputed there.)
     """
 
-    def sample(sub, data_dev):
-        batch = engine.sample_round_batches(sub, data_dev, **sample_kw)
-        return constrain(batch) if constrain is not None else batch
+    def sample(key, data_dev):
+        """(next chain key, the round batch drawn with its split)."""
+        with jax.named_scope("fl_sample"):
+            key, sub = jax.random.split(key)
+            batch = engine.sample_round_batches(sub, data_dev, **sample_kw)
+            if constrain is not None:
+                batch = constrain(batch)
+            return key, batch
 
     def _mets(metrics):
         return {"tau_eff": metrics["tau_eff"], "health": metrics["health"]}
@@ -203,8 +215,7 @@ def build_chunk(eng: EngineConfig, grad_fn, la_fn, sample_kw: dict, *,
     def serial_chunk(state, key, data_dev, length):
         def body(carry, _):
             st, k = carry
-            k, sub = jax.random.split(k)
-            batch = sample(sub, data_dev)
+            k, batch = sample(k, data_dev)
             st, metrics = engine.round_core(eng, grad_fn, la_fn, st, batch,
                                              **maps)
             return (st, k), _mets(metrics)
@@ -222,13 +233,11 @@ def build_chunk(eng: EngineConfig, grad_fn, la_fn, sample_kw: dict, *,
             # second, discarded gather (length is trace-time static, and
             # the draws/key chain are identical either way)
             return serial_chunk(state, key, data_dev, 1)
-        k1, sub0 = jax.random.split(key)
-        batch0 = sample(sub0, data_dev)
+        k1, batch0 = sample(key, data_dev)
 
         def body(carry, _):
             st, _, k, batch = carry
-            k_next, sub = jax.random.split(k)
-            nb = sample(sub, data_dev)          # round t+1, drawn during t
+            k_next, nb = sample(k, data_dev)    # round t+1, drawn during t
             st, metrics = engine.round_core(eng, grad_fn, la_fn, st, batch,
                                              **maps)
             return (st, k, k_next, nb), _mets(metrics)
@@ -453,6 +462,7 @@ class _EngineBackend:
                                                      masks)
         return self._place_state(new_state)
 
+    @_span("fl.apply_prune")
     def apply_prune(self, state: dict, mode: str, kept, *,
                     compact_existing: bool = False):
         """Apply a FedAP decision.  mask: inject keep-masks into the carry
@@ -534,16 +544,19 @@ class LocalScanBackend(_EngineBackend):
             self._data_cache["local"] = d
         return d
 
+    @_span("fl.run_chunk")
     def run_chunk(self, state, key, length):
         self._secure_loans()   # the jitted chunk donates `state`
         return self._compiled().chunk(state, key, self.device_data(),
                                       length=length)
 
+    @_span("fl.evaluate")
     def evaluate(self, state):
         d = self.device_data()
         return self._compiled().evaluate(state["params"], d["test_x"],
                                          d["test_y"])
 
+    @_span("fl.prune_decision")
     def prune_decision(self, state, init_params):
         from repro.core import fedap
 
@@ -729,6 +742,7 @@ class MeshBackend(_EngineBackend):
     def chunk(self):
         return self._programs()
 
+    @_span("fl.run_chunk")
     def run_chunk(self, state, key, length):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -740,12 +754,14 @@ class MeshBackend(_EngineBackend):
         return self._programs()(state, key, self.device_data(),
                                 length=length)
 
+    @_span("fl.evaluate")
     def evaluate(self, state):
         d = self.device_data()
         return self._eval_program()(state["params"], d["test_x"],
                                     d["test_y"])
 
     # -- pod-side FedAP ------------------------------------------------------
+    @_span("fl.prune_decision")
     def prune_decision(self, state, init_params):
         from repro.core import fedap
 
@@ -756,6 +772,7 @@ class MeshBackend(_EngineBackend):
             rng=np.random.default_rng(self.cfg.seed),
             mesh=self.mesh, client_axes=self.plan.client_axes)
 
+    @_span("fl.apply_prune")
     def apply_prune(self, state, mode, kept, *, compact_existing=False):
         if mode != "mask":
             return self._sharded_shrink(state, kept,
@@ -910,6 +927,7 @@ class PlanExecutor:
                 i += 1
             artifacts[k] = value
 
+        @_span("fl.checkpoint")
         def write_checkpoint(cursor):
             from repro.reliability.checkpoint import (
                 plan_spec,

@@ -135,6 +135,20 @@ ALGORITHMS = ("fedavg", "fedprox", "feddyn")
 
 GUARD_MODES = ("off", "reject_client", "skip_round")
 
+# The named scopes of one scan iteration, in program order.  Each compiled
+# instruction's ``op_name`` metadata carries the scope of the phase whose
+# work it does, so a device trace splits a round's time by phase:
+#   fl_sample           device-side client selection and batch gather
+#                       (``backend.build_chunk``)
+#   fl_client_train     step (2): the local epochs of every selected client
+#   fl_aggregate        fault injection, health guard, FedAvg, FedDyn
+#   fl_server_update    step (5a): the FedDU server scan and its guard
+#   fl_server_momentum  step (5b): FedDUM, the new state's masks, the
+#                       round-discard select
+# The scopes are metadata only: they change no arithmetic.
+ROUND_PHASES = ("fl_sample", "fl_client_train", "fl_aggregate",
+                "fl_server_update", "fl_server_momentum")
+
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -401,221 +415,236 @@ def round_core(cfg: EngineConfig, grad_fn: Callable, loss_and_acc_fn: Callable,
     else:
         _m = lambda t: t
 
-    params = _m(state["params"])
-    lr = cfg.lr * (cfg.lr_decay ** state["round"])
+    with jax.named_scope("fl_client_train"):
+        params = _m(state["params"])
+        lr = cfg.lr * (cfg.lr_decay ** state["round"])
 
-    # (2) local epochs, vmapped over the client dim — clients diverge inside
-    # the program; there is NO collective over the client axis here.
-    if cfg.local_momentum == "communicated":
-        m0 = _m(state["global_m"])             # FedDA: broadcast momentum
-    else:
-        m0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    client_map = client_map or jax.vmap
-    if cfg.algorithm == "feddyn":
-        if "sel" not in batch:
-            raise ValueError(
-                "algorithm='feddyn' needs batch['sel'] (the selected "
-                "clients' global indices) to gather per-client state — "
-                "sample_round_batches emits it")
-        h_all = state["client_state"]["per_client"]["h"]
-        h_sel = _m(jax.tree.map(lambda x: x[batch["sel"]], h_all))
-        locals_, local_ms = client_map(
-            lambda b, hk: local_train(cfg, grad_fn, params, m0, b, lr,
-                                      anchor=params, h=hk))(
-                batch["client"], h_sel)
-    elif cfg.algorithm == "fedprox":
-        locals_, local_ms = client_map(
-            lambda b: local_train(cfg, grad_fn, params, m0, b, lr,
-                                  anchor=params))(batch["client"])
-    else:
-        locals_, local_ms = client_map(
-            lambda b: local_train(cfg, grad_fn, params, m0, b,
-                                  lr))(batch["client"])
-
-    # Deterministic fault injection (test-only): corrupt the uploaded
-    # updates BEFORE aggregation / the guard.  A static python unroll over
-    # the frozen fault tuple — the faults are part of the traced graph.
-    if cfg.faults:  # lint: static-branch (config-keyed)
-        sel_ids = batch.get("sel")
-        if sel_ids is None:
-            sel_ids = jnp.arange(batch["sizes"].shape[0], dtype=jnp.int32)
-        for f in cfg.faults:
-            locals_ = f.apply_client(locals_, params, sel_ids,
-                                     state["round"])
-
-    # In-scan health guard: all-device finiteness check per client.  A
-    # rejected client is scrubbed back to the broadcast point (so NaN/inf
-    # never reaches a reduction — 0-weight alone would not neutralize NaN)
-    # and contributes zero aggregation weight via the delta-form path.
-    sizes = batch["sizes"].astype(jnp.float32)
-    active = batch.get("active")
-    guard_on = cfg.guard != "off"
-    base_act = (active.astype(jnp.float32) if active is not None
-                else jnp.ones_like(sizes))
-    if guard_on:
-        _cvec = lambda v, leaf: v.reshape(v.shape + (1,) * (leaf.ndim - 1))
-        client_ok = jnp.ones(sizes.shape, bool)
-        checked = [locals_]
+        # (2) local epochs, vmapped over the client dim — clients diverge
+        # inside the program; there is NO collective over the client axis.
         if cfg.local_momentum == "communicated":
-            checked.append(local_ms)
-        for tree in checked:
-            for leaf in jax.tree.leaves(tree):
-                client_ok = client_ok & jnp.all(
-                    jnp.isfinite(leaf), axis=tuple(range(1, leaf.ndim)))
-        rejected = jnp.sum(base_act * (~client_ok).astype(jnp.float32))
-        act = base_act * client_ok.astype(jnp.float32)
-        _scrub = lambda trees, base: jax.tree.map(
-            lambda l, b: jnp.where(_cvec(client_ok, l), l,
-                                   b.astype(l.dtype)), trees, base)
-        locals_ = _scrub(locals_, params)
+            m0 = _m(state["global_m"])         # FedDA: broadcast momentum
+        else:
+            m0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                              params)
+        client_map = client_map or jax.vmap
+        if cfg.algorithm == "feddyn":
+            if "sel" not in batch:
+                raise ValueError(
+                    "algorithm='feddyn' needs batch['sel'] (the selected "
+                    "clients' global indices) to gather per-client state — "
+                    "sample_round_batches emits it")
+            h_all = state["client_state"]["per_client"]["h"]
+            h_sel = _m(jax.tree.map(lambda x: x[batch["sel"]], h_all))
+            locals_, local_ms = client_map(
+                lambda b, hk: local_train(cfg, grad_fn, params, m0, b, lr,
+                                          anchor=params, h=hk))(
+                    batch["client"], h_sel)
+        elif cfg.algorithm == "fedprox":
+            locals_, local_ms = client_map(
+                lambda b: local_train(cfg, grad_fn, params, m0, b, lr,
+                                      anchor=params))(batch["client"])
+        else:
+            locals_, local_ms = client_map(
+                lambda b: local_train(cfg, grad_fn, params, m0, b,
+                                      lr))(batch["client"])
+
+    with jax.named_scope("fl_aggregate"):
+        # Deterministic fault injection (test-only): corrupt the uploaded
+        # updates BEFORE aggregation / the guard.  A static python unroll
+        # over the frozen fault tuple — the faults are part of the traced
+        # graph.
+        if cfg.faults:  # lint: static-branch (config-keyed)
+            sel_ids = batch.get("sel")
+            if sel_ids is None:
+                sel_ids = jnp.arange(batch["sizes"].shape[0],
+                                     dtype=jnp.int32)
+            for f in cfg.faults:
+                locals_ = f.apply_client(locals_, params, sel_ids,
+                                         state["round"])
+
+        # In-scan health guard: all-device finiteness check per client.  A
+        # rejected client is scrubbed back to the broadcast point (so
+        # NaN/inf never reaches a reduction — 0-weight alone would not
+        # neutralize NaN) and contributes zero aggregation weight via the
+        # delta-form path.
+        sizes = batch["sizes"].astype(jnp.float32)
+        active = batch.get("active")
+        guard_on = cfg.guard != "off"
+        base_act = (active.astype(jnp.float32) if active is not None
+                    else jnp.ones_like(sizes))
+        if guard_on:
+            _cvec = lambda v, leaf: v.reshape(
+                v.shape + (1,) * (leaf.ndim - 1))
+            client_ok = jnp.ones(sizes.shape, bool)
+            checked = [locals_]
+            if cfg.local_momentum == "communicated":
+                checked.append(local_ms)
+            for tree in checked:
+                for leaf in jax.tree.leaves(tree):
+                    client_ok = client_ok & jnp.all(
+                        jnp.isfinite(leaf), axis=tuple(range(1, leaf.ndim)))
+            rejected = jnp.sum(base_act * (~client_ok).astype(jnp.float32))
+            act = base_act * client_ok.astype(jnp.float32)
+            _scrub = lambda trees, base: jax.tree.map(
+                lambda l, b: jnp.where(_cvec(client_ok, l), l,
+                                       b.astype(l.dtype)), trees, base)
+            locals_ = _scrub(locals_, params)
+            if cfg.local_momentum == "communicated":
+                local_ms = _scrub(local_ms, m0)
+        else:
+            rejected = jnp.zeros(())
+            act = base_act
+
+        # (3-4) upload + FedAvg: ONE weighted reduction over the client
+        # axis.  With a dropout mask or an active guard the reduction runs
+        # in DELTA form around the broadcast point (an all-dropped round is
+        # exactly a no-op); otherwise the legacy direct einsum —
+        # bit-identical to the pre-dropout engine.
+        if active is not None or guard_on:
+            w = sizes * act
+            w = w / jnp.maximum(jnp.sum(w), 1e-12)
+
+            def agg_tree(trees, base):
+                def one(l, b):
+                    d = jnp.einsum("c,c...->...", w, l.astype(jnp.float32)
+                                   - b.astype(jnp.float32))
+                    return (b.astype(jnp.float32) + d).astype(l.dtype)
+                return jax.tree.map(one, trees, base)
+
+            w_half = agg_tree(locals_, params)
+            new_global_m = (agg_tree(local_ms, m0)
+                            if cfg.local_momentum == "communicated" else None)
+        else:
+            w = sizes / jnp.sum(sizes)
+            agg = lambda l: jnp.einsum(
+                "c,c...->...", w, l.astype(jnp.float32)).astype(l.dtype)
+            w_half = jax.tree.map(agg, locals_)
+            new_global_m = (jax.tree.map(agg, local_ms)
+                            if cfg.local_momentum == "communicated" else None)
+
+        # FedDyn: update the per-client correction of the selected ACTIVE
+        # clients (scatter), the server average, and pull w_half toward the
+        # implicit consensus point — all BEFORE the FedDU server update,
+        # which then trains from the corrected model.
+        new_client_state = state.get("client_state")
+        if cfg.algorithm == "feddyn":
+            alpha = cfg.feddyn.alpha
+            n_total = jax.tree.leaves(h_all)[0].shape[0]
+            bcast = lambda v, leaf: v.reshape(
+                v.shape + (1,) * (leaf.ndim - 1))
+            drift = jax.tree.map(
+                lambda l, p0: l.astype(jnp.float32) - p0.astype(jnp.float32),
+                locals_, params)
+            h_sel_new = jax.tree.map(
+                lambda hk, d: hk - alpha * bcast(act, d) * d, h_sel, drift)
+            h_new = jax.tree.map(
+                lambda ha, hs: ha.at[batch["sel"]].set(hs.astype(ha.dtype)),
+                h_all, h_sel_new)
+            h_shared_new = jax.tree.map(
+                lambda hs, d: hs - (alpha / n_total)
+                * jnp.einsum("c,c...->...", act, d),
+                _m(state["client_state"]["shared"]["h"]), drift)
+            if alpha > 0:  # lint: static-branch (at alpha == 0, h is identically zero)
+                w_half = jax.tree.map(
+                    lambda wh, hs: (wh.astype(jnp.float32) - hs / alpha
+                                    ).astype(wh.dtype), w_half, h_shared_new)
+            new_client_state = {"per_client": {"h": _m(h_new)},
+                                "shared": {"h": _m(h_shared_new)}}
+
+    with jax.named_scope("fl_server_update"):
+        # (5a) FedDU dynamic server update (Formulas 4-7).  acc comes from
+        # the FIRST server step's own forward — no separate evaluation pass.
+        if cfg.use_server_update:
+            tau = jax.tree.leaves(batch["server"])[0].shape[0]
+            la_grad = jax.value_and_grad(loss_and_acc_fn, has_aux=True)
+
+            def sstep(carry, b):
+                p, acc0, is_first = carry
+                (_, acc), g = la_grad(p, b)
+                g = _m(g)
+                acc0 = jnp.where(is_first, acc, acc0)
+                p = jax.tree.map(
+                    lambda pi, gi: (pi - lr * gi).astype(pi.dtype), p, g)
+                return (p, acc0, jnp.zeros((), bool)), None
+
+            def server_scan(w, server):
+                (w_end, acc, _), _ = jax.lax.scan(
+                    sstep, (w, jnp.zeros(()), jnp.ones((), bool)), server)
+                return w_end, acc
+
+            w_end, acc = (server_map or (lambda f: f))(server_scan)(
+                w_half, batch["server"])
+            # Formula 6 via the telescoping identity: mean path gradient.
+            g0 = jax.tree.map(
+                lambda a, b_: (a.astype(jnp.float32) - b_.astype(jnp.float32))
+                / (tau * lr), w_half, w_end)
+            t_eff = tau_eff(cfg.feddu, acc=acc, round_idx=state["round"],
+                            n0=batch["n0"], n_prime=jnp.sum(batch["sizes"]),
+                            d_round=batch["d_round"],
+                            d_server=batch["d_server"], tau=tau)
+            proposed = feddu_apply(w_half, g0, t_eff, lr)
+        else:
+            proposed = w_half
+            t_eff = jnp.zeros(())
+            acc = jnp.zeros(())
+
+        # Server-step guard: a diverged FedDU proposal (non-finite model,
+        # tau_eff or gate accuracy) falls back to the plain aggregate
+        # w_half.
+        if guard_on and cfg.use_server_update:
+            server_ok = jnp.isfinite(t_eff) & jnp.isfinite(acc)
+            for leaf in jax.tree.leaves(proposed):
+                server_ok = server_ok & jnp.all(jnp.isfinite(leaf))
+            proposed = jax.tree.map(
+                lambda pr, wh: jnp.where(server_ok, pr, wh), proposed, w_half)
+            t_eff = jnp.where(server_ok, t_eff, 0.0)
+            acc = jnp.where(server_ok, acc, 0.0)
+        else:
+            server_ok = jnp.ones((), bool)
+
+    with jax.named_scope("fl_server_momentum"):
+        # (5b) FedDUM server momentum on the pseudo-gradient (Formulas
+        # 8/12).
+        if cfg.server_momentum:
+            pseudo = server_pseudo_gradient(params, proposed)
+            new_params, new_server_m = server_momentum_step(
+                params, state["server_m"], pseudo, cfg.feddum)
+        else:
+            new_params, new_server_m = proposed, state["server_m"]
+
+        new_state = {"params": _m(new_params), "server_m": _m(new_server_m),
+                     "round": state["round"] + 1}
         if cfg.local_momentum == "communicated":
-            local_ms = _scrub(local_ms, m0)
-    else:
-        rejected = jnp.zeros(())
-        act = base_act
+            new_state["global_m"] = _m(new_global_m)
+        if new_client_state is not None:
+            new_state["client_state"] = new_client_state
+        if cfg.use_masks:
+            new_state["masks"] = masks
+            if cfg.masked_compute == "kernel":
+                new_state["filter_masks"] = state["filter_masks"]
 
-    # (3-4) upload + FedAvg: ONE weighted reduction over the client axis.
-    # With a dropout mask or an active guard the reduction runs in DELTA
-    # form around the broadcast point (an all-dropped round is exactly a
-    # no-op); otherwise the legacy direct einsum — bit-identical to the
-    # pre-dropout engine.
-    if active is not None or guard_on:
-        w = sizes * act
-        w = w / jnp.maximum(jnp.sum(w), 1e-12)
+        # Round discard: with every client rejected there is no information
+        # in the round (reject_client), and under skip_round ANY rejection
+        # voids it — restore the round-start carry (round counter still
+        # advances, so the key chain and lr schedule stay aligned with a
+        # fault-free run).
+        if guard_on:
+            survivors = jnp.sum(act) > 0
+            if cfg.guard == "reject_client":
+                discard = ~survivors
+            else:  # skip_round
+                discard = (~survivors) | (rejected > 0) | (~server_ok)
+            health = rejected + (~server_ok).astype(jnp.float32)
+            for k in ("params", "server_m", "global_m", "client_state"):
+                if k in new_state:
+                    new_state[k] = jax.tree.map(
+                        lambda o, n: jnp.where(discard, o, n),
+                        state[k], new_state[k])
+            t_eff = jnp.where(discard, 0.0, t_eff)
+            acc = jnp.where(discard, 0.0, acc)
+        else:
+            health = jnp.zeros(())
 
-        def agg_tree(trees, base):
-            def one(l, b):
-                d = jnp.einsum("c,c...->...", w, l.astype(jnp.float32)
-                               - b.astype(jnp.float32))
-                return (b.astype(jnp.float32) + d).astype(l.dtype)
-            return jax.tree.map(one, trees, base)
-
-        w_half = agg_tree(locals_, params)
-        new_global_m = (agg_tree(local_ms, m0)
-                        if cfg.local_momentum == "communicated" else None)
-    else:
-        w = sizes / jnp.sum(sizes)
-        agg = lambda l: jnp.einsum(
-            "c,c...->...", w, l.astype(jnp.float32)).astype(l.dtype)
-        w_half = jax.tree.map(agg, locals_)
-        new_global_m = (jax.tree.map(agg, local_ms)
-                        if cfg.local_momentum == "communicated" else None)
-
-    # FedDyn: update the per-client correction of the selected ACTIVE
-    # clients (scatter), the server average, and pull w_half toward the
-    # implicit consensus point — all BEFORE the FedDU server update, which
-    # then trains from the corrected model.
-    new_client_state = state.get("client_state")
-    if cfg.algorithm == "feddyn":
-        alpha = cfg.feddyn.alpha
-        n_total = jax.tree.leaves(h_all)[0].shape[0]
-        bcast = lambda v, leaf: v.reshape(v.shape + (1,) * (leaf.ndim - 1))
-        drift = jax.tree.map(
-            lambda l, p0: l.astype(jnp.float32) - p0.astype(jnp.float32),
-            locals_, params)
-        h_sel_new = jax.tree.map(
-            lambda hk, d: hk - alpha * bcast(act, d) * d, h_sel, drift)
-        h_new = jax.tree.map(
-            lambda ha, hs: ha.at[batch["sel"]].set(hs.astype(ha.dtype)),
-            h_all, h_sel_new)
-        h_shared_new = jax.tree.map(
-            lambda hs, d: hs - (alpha / n_total)
-            * jnp.einsum("c,c...->...", act, d),
-            _m(state["client_state"]["shared"]["h"]), drift)
-        if alpha > 0:  # lint: static-branch (at alpha == 0, h is identically zero)
-            w_half = jax.tree.map(
-                lambda wh, hs: (wh.astype(jnp.float32) - hs / alpha
-                                ).astype(wh.dtype), w_half, h_shared_new)
-        new_client_state = {"per_client": {"h": _m(h_new)},
-                            "shared": {"h": _m(h_shared_new)}}
-
-    # (5a) FedDU dynamic server update (Formulas 4-7).  acc comes from the
-    # FIRST server step's own forward — no separate evaluation pass.
-    if cfg.use_server_update:
-        tau = jax.tree.leaves(batch["server"])[0].shape[0]
-        la_grad = jax.value_and_grad(loss_and_acc_fn, has_aux=True)
-
-        def sstep(carry, b):
-            p, acc0, is_first = carry
-            (_, acc), g = la_grad(p, b)
-            g = _m(g)
-            acc0 = jnp.where(is_first, acc, acc0)
-            p = jax.tree.map(lambda pi, gi: (pi - lr * gi).astype(pi.dtype), p, g)
-            return (p, acc0, jnp.zeros((), bool)), None
-
-        def server_scan(w, server):
-            (w_end, acc, _), _ = jax.lax.scan(
-                sstep, (w, jnp.zeros(()), jnp.ones((), bool)), server)
-            return w_end, acc
-
-        w_end, acc = (server_map or (lambda f: f))(server_scan)(
-            w_half, batch["server"])
-        # Formula 6 via the telescoping identity: mean path gradient.
-        g0 = jax.tree.map(
-            lambda a, b_: (a.astype(jnp.float32) - b_.astype(jnp.float32))
-            / (tau * lr), w_half, w_end)
-        t_eff = tau_eff(cfg.feddu, acc=acc, round_idx=state["round"],
-                        n0=batch["n0"], n_prime=jnp.sum(batch["sizes"]),
-                        d_round=batch["d_round"], d_server=batch["d_server"],
-                        tau=tau)
-        proposed = feddu_apply(w_half, g0, t_eff, lr)
-    else:
-        proposed = w_half
-        t_eff = jnp.zeros(())
-        acc = jnp.zeros(())
-
-    # Server-step guard: a diverged FedDU proposal (non-finite model,
-    # tau_eff or gate accuracy) falls back to the plain aggregate w_half.
-    if guard_on and cfg.use_server_update:
-        server_ok = jnp.isfinite(t_eff) & jnp.isfinite(acc)
-        for leaf in jax.tree.leaves(proposed):
-            server_ok = server_ok & jnp.all(jnp.isfinite(leaf))
-        proposed = jax.tree.map(
-            lambda pr, wh: jnp.where(server_ok, pr, wh), proposed, w_half)
-        t_eff = jnp.where(server_ok, t_eff, 0.0)
-        acc = jnp.where(server_ok, acc, 0.0)
-    else:
-        server_ok = jnp.ones((), bool)
-
-    # (5b) FedDUM server momentum on the pseudo-gradient (Formulas 8/12).
-    if cfg.server_momentum:
-        pseudo = server_pseudo_gradient(params, proposed)
-        new_params, new_server_m = server_momentum_step(
-            params, state["server_m"], pseudo, cfg.feddum)
-    else:
-        new_params, new_server_m = proposed, state["server_m"]
-
-    new_state = {"params": _m(new_params), "server_m": _m(new_server_m),
-                 "round": state["round"] + 1}
-    if cfg.local_momentum == "communicated":
-        new_state["global_m"] = _m(new_global_m)
-    if new_client_state is not None:
-        new_state["client_state"] = new_client_state
-    if cfg.use_masks:
-        new_state["masks"] = masks
-        if cfg.masked_compute == "kernel":
-            new_state["filter_masks"] = state["filter_masks"]
-
-    # Round discard: with every client rejected there is no information in
-    # the round (reject_client), and under skip_round ANY rejection voids
-    # it — restore the round-start carry (round counter still advances, so
-    # the key chain and lr schedule stay aligned with a fault-free run).
-    if guard_on:
-        survivors = jnp.sum(act) > 0
-        if cfg.guard == "reject_client":
-            discard = ~survivors
-        else:  # skip_round
-            discard = (~survivors) | (rejected > 0) | (~server_ok)
-        health = rejected + (~server_ok).astype(jnp.float32)
-        for k in ("params", "server_m", "global_m", "client_state"):
-            if k in new_state:
-                new_state[k] = jax.tree.map(
-                    lambda o, n: jnp.where(discard, o, n),
-                    state[k], new_state[k])
-        t_eff = jnp.where(discard, 0.0, t_eff)
-        acc = jnp.where(discard, 0.0, acc)
-    else:
-        health = jnp.zeros(())
     return new_state, {"tau_eff": t_eff, "server_acc": acc,
                        "health": health}
 
