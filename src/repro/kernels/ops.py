@@ -49,6 +49,6 @@ def ssd_scan(x, bmat, cmat, dt, a_log, d, dt_bias, *, chunk=128):
                 interpret=_interpret())
 
 
-def masked_matmul(x, w, block_mask, *, block_m=128, block_n=128, block_k=128):
-    return _masked_mm(x, w, block_mask, block_m=block_m, block_n=block_n,
-                      block_k=block_k, interpret=_interpret())
+def masked_matmul(x, w, block_mask, *, block_n=128):
+    return _masked_mm(x, w, block_mask, block_n=block_n,
+                      interpret=_interpret())

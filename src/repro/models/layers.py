@@ -523,8 +523,8 @@ def masked_dense(x, w, mask, b=None, *, block: int = 128):
     (partially-kept blocks are computed and re-masked elementwise — exact
     for 0/1 masks).  The batch dimension M does NOT gate the kernel: real
     batch sizes (10, 32) are zero-padded up to the 8-row sublane multiple
-    (a small M block of their own, not a full ``block`` rows) and the
-    result sliced back, so the kernel path is live in training and
+    (the kernel chooses its tiles from the padded shape) and the result
+    sliced back, so the kernel path is live in training and
     serving alike.  Unaligned K/N fall back to masking the XLA matmul.
 
     The kernel carries a ``jax.custom_vjp`` whose backward Pallas kernels
@@ -540,15 +540,11 @@ def masked_dense(x, w, mask, b=None, *, block: int = 128):
         block_mask = jnp.max(mask.reshape(n // block, block), axis=1)
         # Only the LANE dims (K, N) need the mask-granularity block; the
         # sublane dim M pads to the next 8-row multiple (<= 7 wasted rows
-        # for ANY batch size, never a full ``block`` rows) and takes the
-        # largest 8-aligned tile that divides it: gcd(mp, block) is a
-        # multiple of 8 whenever both are, divides mp, and is <= block.
+        # for ANY batch size, never a full ``block`` rows).  The kernel
+        # picks its tiles from the padded shapes.
         m_pad = -m % 8
-        mp = m + m_pad
-        bm = math.gcd(mp, block)
         xp = jnp.pad(x, ((0, m_pad), (0, 0))) if m_pad else x
-        y = masked_matmul(xp, w, block_mask, block_m=bm, block_n=block,
-                          block_k=block)
+        y = masked_matmul(xp, w, block_mask, block_n=block)
         if m_pad:
             y = y[:m]
     else:

@@ -785,8 +785,9 @@ class TestMaskedModelRouting:
         assert len(calls) == 2           # the kernel branch ran both times
 
     def test_masked_dense_threads_nondefault_block(self):
-        """block=64 must thread into ALL of block_m/n/k, not just block_n
-        (K=N=192 passes the 64-gate but is not 128-aligned)."""
+        """block=64 must thread into the kernel's mask granularity (K=N=192
+        passes the 64-gate but is not 128-aligned: the tiles take each
+        dimension whole)."""
         from repro.models import masked_dense
 
         rng = np.random.default_rng(2)

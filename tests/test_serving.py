@@ -3,8 +3,9 @@
 * **Mask == shrink at decode.**  Serving a FedAP mask-mode checkpoint
   through the block-skipping kernel (``decode_step(..., masks=)``) and
   serving its structural compaction (``shrink_ffn_at``) are the same
-  model: per-step logits agree <= 1e-5, all-ones masks are bit-exact
-  against the plain dense step.
+  model: per-step logits agree <= 1e-5; all-ones masks agree with the
+  plain dense step to float32 accumulation and are bit-identical to
+  themselves across steps.
 * **Continuous batching is just batching.**  The ``DecodeEngine`` —
   ragged prompts, chunked prefill, slot reuse, on-device done-mask —
   emits token-for-token what a naive one-sequence-at-a-time greedy loop
@@ -108,12 +109,17 @@ class TestPrunedDecodeParity:
                                        atol=1e-5, rtol=1e-5)
 
     def test_all_ones_masks_bit_exact(self, world):
-        """masks of all-ones must not perturb the dense step at all."""
+        """masks of all-ones must not perturb the dense step: the kernel
+        path agrees with it within float32 accumulation over the longest
+        contraction (d_ff), and is bit-identical to itself, step after
+        step (bit equality with XLA's dot would depend on the host's dot
+        codegen)."""
         model, params, _, _, _, _, _ = world
         ones = {"mlp": jnp.ones((CFG.num_layers, CFG.d_ff), jnp.float32)}
         b, cache_len = 2, 8
         ca = model.init_cache(b, cache_len)
         cb = model.init_cache(b, cache_len)
+        cc = model.init_cache(b, cache_len)
         rng = np.random.default_rng(2)
         for _ in range(3):
             tok = jnp.asarray(rng.integers(0, CFG.vocab_size, (b, 1)),
@@ -121,7 +127,13 @@ class TestPrunedDecodeParity:
             la, ca = model.decode_step(params, ca, {"tokens": tok})
             lb, cb = model.decode_step(params, cb, {"tokens": tok},
                                        masks=ones)
-            assert np.array_equal(np.asarray(la), np.asarray(lb))
+            lc, cc = model.decode_step(params, cc, {"tokens": tok},
+                                       masks=ones)
+            assert np.array_equal(np.asarray(lb), np.asarray(lc))
+            tol = (CFG.d_ff * np.finfo(np.float32).eps
+                   * float(np.abs(np.asarray(la)).max()))
+            np.testing.assert_allclose(np.asarray(lb), np.asarray(la),
+                                       rtol=0, atol=tol)
 
     def test_masked_engine_equals_shrunk_engine(self, world):
         """End-to-end: the two pruned serve modes emit identical tokens."""

@@ -60,7 +60,7 @@ def test_masked_matmul_forward_compiles(one_chip, dtype):
     # M=8: the decode wave's 8 slots, one 8-row block (masked_dense pads
     # M only to 8, also for bf16, whose native tile is 16 rows)
     m = 8
-    fwd = jax.jit(lambda x, w, mask: masked_matmul(x, w, mask, block_m=m))
+    fwd = jax.jit(lambda x, w, mask: masked_matmul(x, w, mask))
     compiled = fwd.lower(_spec((m, D_MODEL), dtype, one_chip),
                          _spec((D_MODEL, D_FF), dtype, one_chip),
                          _spec((D_FF // 128,), jnp.float32, one_chip)
@@ -74,7 +74,7 @@ def test_masked_matmul_gradient_compiles(one_chip, dtype):
     m = 8
 
     def loss(x, w, mask):
-        y = masked_matmul(x, w, mask, block_m=m)
+        y = masked_matmul(x, w, mask)
         return jnp.sum(y.astype(jnp.float32))
 
     grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
@@ -84,6 +84,67 @@ def test_masked_matmul_gradient_compiles(one_chip, dtype):
                           ).compile()
     # forward + dx + dw kernels
     assert _mosaic_calls(compiled) >= 3
+
+
+# The training shape: one client's 2 sequences of 512 tokens against an
+# FFN projection, float32 master weights and bfloat16 weights.  A tile
+# Mosaic refuses, or one over the kernel's VMEM limit, fails here.
+TRAIN_M = 1024
+DTYPES = [jnp.float32, jnp.bfloat16]
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_masked_matmul_training_forward_compiles(one_chip, dtype):
+    fwd = jax.jit(lambda x, w, mask: masked_matmul(x, w, mask))
+    compiled = fwd.lower(_spec((TRAIN_M, D_MODEL), dtype, one_chip),
+                         _spec((D_MODEL, D_FF), dtype, one_chip),
+                         _spec((D_FF // 128,), jnp.float32, one_chip)
+                         ).compile()
+    assert _mosaic_calls(compiled) >= 1
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_masked_matmul_training_gradient_compiles(one_chip, dtype):
+    def loss(x, w, mask):
+        y = masked_matmul(x, w, mask)
+        return jnp.sum(y.astype(jnp.float32))
+
+    # the two clients of a round, vmapped as the round engine does
+    grad = jax.jit(jax.vmap(jax.value_and_grad(loss, argnums=(0, 1)),
+                            in_axes=(0, 0, None)))
+    compiled = grad.lower(_spec((2, TRAIN_M, D_MODEL), dtype, one_chip),
+                          _spec((2, D_MODEL, D_FF), dtype, one_chip),
+                          _spec((D_FF // 128,), jnp.float32, one_chip)
+                          ).compile()
+    assert _mosaic_calls(compiled) >= 3
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_masked_ffn_layer_scan_gradient_compiles(one_chip, dtype):
+    """The FFN's masked projections in a scan over 2 layers, differentiated
+    as a client's local steps are: XLA fuses each weight gradient's kernel
+    with the in-place update of the scan's stacked gradient and gives that
+    fusion only the default scoped VMEM, so a tile that fits the kernel
+    alone can fail here."""
+    layers = 2
+
+    def loss(params, h, mask):
+        def layer(h, lp):
+            wi, wg, wo = lp
+            up = masked_matmul(h, wi, mask)
+            gate = masked_matmul(h, wg, mask)
+            return h + (jax.nn.silu(gate) * up) @ wo, None
+
+        h, _ = jax.lax.scan(jax.checkpoint(layer), h, params)
+        return jnp.sum(h.astype(jnp.float32) ** 2)
+
+    grad = jax.jit(jax.grad(loss))
+    up = _spec((layers, D_MODEL, D_FF), dtype, one_chip)
+    compiled = grad.lower(
+        (up, up, _spec((layers, D_FF, D_MODEL), dtype, one_chip)),
+        _spec((TRAIN_M, D_MODEL), dtype, one_chip),
+        _spec((D_FF // 128,), jnp.float32, one_chip)).compile()
+    assert _mosaic_calls(compiled) >= 6
 
 
 def test_decode_attention_with_lengths_compiles(one_chip):
