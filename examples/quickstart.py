@@ -49,7 +49,11 @@ def main():
         print(f"{r:>5}  {a:.3f}  {t:8.3f}")
 
     prune = res.artifacts["prune"]
-    live = sum(int(jnp.sum(m)) for m in jax.tree.leaves(res.state["masks"]))
+    # the masks are factored (size 1 on every axis FedAP never prunes):
+    # count live coordinates at each parameter's own shape
+    live = sum(int(jnp.sum(jnp.broadcast_to(m, p.shape)))
+               for p, m in zip(jax.tree.leaves(res.params),
+                               jax.tree.leaves(res.state["masks"])))
     print(f"\nFedAP: global rate p*={prune['p_star']:.3f}, kept filters "
           f"{prune['kept_counts']}")
     print(f"masked params {live:,} live of {tree_size(res.params):,} "

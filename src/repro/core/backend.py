@@ -139,12 +139,18 @@ def init_filter_masks(model, params):
 # rows for scanned stacks).
 
 def param_masks_for(model, params, kept):
-    """Param-structured 0/1 masks for the carry (``state["masks"]``)."""
+    """Param-structured, factored 0/1 masks for the carry
+    (``state["masks"]``): each leaf keeps only the axes the model can
+    prune (``pruning.param_masks``), so ``kept={}`` gives the all-ones
+    masks of the slot's final shapes.  A model with no pruning seam prunes
+    nothing: every leaf is ``(1,) * ndim``."""
     if hasattr(model, "param_masks"):
         return model.param_masks(params, kept)
     from repro.core import pruning
 
-    return pruning.param_masks(params, model.prune_spec(params), kept)
+    spec = (model.prune_spec(params) if hasattr(model, "prune_spec")
+            else pruning.PruneSpec(layers=()))
+    return pruning.param_masks(params, spec, kept)
 
 
 def filter_masks_for(model, params, kept):
@@ -270,7 +276,12 @@ def masked_round_state(state: dict, masks: Any, filter_masks: Any = None
     masked, shapes and shardings — and therefore the compiled (or lowered
     SPMD) program — are untouched.  The canonical
     implementation behind both the executor's ``Prune(mode="mask")`` apply
-    and the pod path's :func:`repro.launch.steps.with_masks`."""
+    and the pod path's :func:`repro.launch.steps.with_masks`.  Masks of a
+    full parameter shape (an older artifact) are reduced to the slot's
+    factored shapes first (``pruning.factor_masks``)."""
+    from repro.core.pruning import factor_masks
+
+    masks = factor_masks(masks, state["masks"])
     new = {k: (jax.tree.map(jnp.zeros_like, v)
                if k in ("server_m", "global_m", "client_state") else v)
            for k, v in state.items()}
@@ -394,13 +405,21 @@ class _EngineBackend:
         """Hook for backends that pin state to explicit shardings."""
         return state
 
+    def _round_state(self, params, filter_masks=None) -> dict:
+        """``engine.init_round_state`` with the model's all-ones factored
+        keep-masks in the mask slot."""
+        masks = (param_masks_for(self.model, params, {})
+                 if self.eng.use_masks else None)
+        return engine.init_round_state(params, self.eng,
+                                       filter_masks=filter_masks,
+                                       num_clients=self._num_clients,
+                                       masks=masks)
+
     def init_state(self, params) -> dict:
         fmasks = (init_filter_masks(self.model, params)
                   if self._kernel_masks else None)
         # the scan chunk donates its input state — never the caller's arrays
-        state = engine.init_round_state(jax.tree.map(jnp.copy, params),
-                                        self.eng, filter_masks=fmasks,
-                                        num_clients=self._num_clients)
+        state = self._round_state(jax.tree.map(jnp.copy, params), fmasks)
         return self._place_state(state)
 
     def restore_state(self, state: dict) -> dict:
@@ -408,7 +427,16 @@ class _EngineBackend:
         on device with dtypes preserved, and the mesh backend re-pins them
         to their ``fl_state_specs`` shardings — f32 arrays round-trip
         through npz bit-exactly, which the resume-bit-identity tests
-        lock."""
+        lock.  Full parameter-shaped keep-masks (a checkpoint written
+        before masks were factored) are reduced to the model's factored
+        shapes, or refused with a ``CheckpointError`` naming the leaf."""
+        if "masks" in state:
+            from repro.core.pruning import factor_masks
+
+            like = jax.eval_shape(
+                lambda p: param_masks_for(self.model, p, {}),
+                state["params"])
+            state = dict(state, masks=factor_masks(state["masks"], like))
         return self._place_state(jax.tree.map(jnp.asarray, state))
 
     def snapshot(self, state: dict):
@@ -452,9 +480,8 @@ class _EngineBackend:
         round_ = state["round"]
         masks = state.get("masks")
         fmasks = state.get("filter_masks")
-        new_state = engine.init_round_state(
-            jax.tree.map(jnp.copy, params), self.eng, filter_masks=fmasks,
-            num_clients=self._num_clients)
+        new_state = self._round_state(jax.tree.map(jnp.copy, params),
+                                      fmasks)
         new_state["round"] = round_
         if masks is not None:
             new_state["masks"] = masks
@@ -492,9 +519,7 @@ class _EngineBackend:
         # FedDyn corrections restart as zeros at the SHRUNK shapes: the old
         # h lives in the pre-prune coordinate system and cannot be compacted
         # meaningfully (the correction re-accumulates within a few rounds)
-        new_state = engine.init_round_state(new_params, self.eng,
-                                            filter_masks=fm,
-                                            num_clients=self._num_clients)
+        new_state = self._round_state(new_params, fm)
         if compact_existing:
             new_state["server_m"] = shrink_params_for(
                 self.model, jax.tree.map(jnp.copy, state["server_m"]), kept)
@@ -828,9 +853,7 @@ class MeshBackend(_EngineBackend):
                 # the compacted model has nothing left to skip
                 fm = (init_filter_masks(self.model, params)
                       if self._kernel_masks else None)
-                new = engine.init_round_state(params, self.eng,
-                                              filter_masks=fm,
-                                              num_clients=self._num_clients)
+                new = self._round_state(params, fm)
                 if compact_existing:
                     new["server_m"] = shrink_params_for(
                         self.model, st["server_m"], kept)

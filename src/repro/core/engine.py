@@ -68,9 +68,16 @@ einsum is used, bit-identical to the pre-dropout engine.
 that rides in the scan carry: every round the engine multiplies params,
 gradients, and momentum buffers by it, so FedAP's static-shape mask mode
 (``repro.core.plan.Prune(mode="mask")``) prunes INSIDE a live compiled
-scan — no shape change, no re-jit.  With all-ones masks the round is
-bit-for-bit the unmasked round, so the masked engine can be compiled once
-up front and the prune event only swaps the carry contents.
+scan — no shape change, no re-jit.  Its leaves are FACTORED: each keeps
+only the axes along which FedAP can zero its parameter and holds every
+other axis at size 1 (the FFN stack's ``wi``/``wg`` [L, 1, d_ff] and
+``wo`` [L, d_ff, 1]; a never-pruned leaf ``(1,) * ndim``), so ``x * m``
+broadcasts and no round pass reads a parameter-sized mask.  The shapes
+come from the model's pruning seam (``pruning.param_masks``,
+``pruning_lm.ffn_param_masks``), not from a decision, so they are final
+from round 0.  With all-ones masks the round is bit-for-bit the unmasked
+round, so the masked engine can be compiled once up front and the prune
+event only swaps the carry contents.
 
 ``cfg.masked_compute`` selects HOW the masked round computes:
 
@@ -217,11 +224,18 @@ def init_client_state(params: Any, cfg: EngineConfig,
 
 def init_round_state(params: Any, cfg: EngineConfig,
                      filter_masks: Any = None,
-                     num_clients: int | None = None) -> dict:
+                     num_clients: int | None = None,
+                     masks: Any = None) -> dict:
     """{"params", "server_m", ["global_m"], ["masks"], ["filter_masks"],
     ["client_state"], "round"} — the scan carry.  Masks start as all-ones
     (a bit-exact no-op round) so a masked engine compiles once and the
     prune event only swaps carry contents.
+
+    ``masks`` (used iff ``cfg.use_masks``) are the model's all-ones
+    factored keep-masks (``backend.param_masks_for(model, params, {})``):
+    their shapes are the slot's for the whole run, so a decision's masks
+    fit it.  Left out, every leaf is ``(1,) * ndim`` — the slot of a
+    model that names no prunable axis.
 
     ``filter_masks`` (required iff ``cfg.masked_compute == "kernel"``) is
     the per-layer {name: [d] 0/1} dict of ``pruning.filter_masks``; its
@@ -240,8 +254,11 @@ def init_round_state(params: Any, cfg: EngineConfig,
     if cfg.algorithm != "fedavg":
         state["client_state"] = init_client_state(params, cfg, num_clients)
     if cfg.use_masks:
-        state["masks"] = jax.tree.map(
-            lambda p: jnp.ones(p.shape, jnp.float32), params)
+        # copy, not asarray: the scan chunk donates the state
+        state["masks"] = (
+            jax.tree.map(lambda p: jnp.ones((1,) * p.ndim, jnp.float32),
+                         params) if masks is None else
+            jax.tree.map(lambda m: jnp.array(m, jnp.float32), masks))
         if cfg.masked_compute == "kernel":
             if filter_masks is None:
                 raise ValueError(
@@ -256,7 +273,9 @@ def init_round_state(params: Any, cfg: EngineConfig,
 
 
 def apply_masks(tree: Any, masks: Any) -> Any:
-    """Multiply a param-structured pytree by 0/1 keep-masks (dtype kept)."""
+    """Multiply a param-structured pytree by 0/1 keep-masks (dtype kept);
+    factored masks broadcast against their leaves (and a leading client
+    dim)."""
     return jax.tree.map(lambda x, m: (x * m).astype(x.dtype), tree, masks)
 
 

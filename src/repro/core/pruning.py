@@ -357,13 +357,34 @@ def filter_masks(params: Any, spec: PruneSpec, kept: Mapping[str, np.ndarray]) -
     return masks
 
 
-def param_masks(params: Any, spec: PruneSpec, kept: Mapping[str, np.ndarray]) -> Any:
-    """Param-structured multiplicative keep-masks — the static-shape dual of
-    :func:`shrink_params`.
+def prunable_axes(params: Any, spec: PruneSpec) -> dict[Path, set]:
+    """``{leaf path: axes}`` — the axes along which ``spec`` can zero each
+    leaf: a layer's filter axis on its weight, and every coupled tensor's
+    coupled axis.  A leaf absent from the map is never pruned."""
+    axes: dict[Path, set] = {}
+    for l in spec.layers:
+        for path, axis in ((l.weight, l.filter_axis),
+                           *((c.path, c.axis) for c in l.coupled)):
+            path = tuple(path)
+            ndim = get_path(params, path).ndim
+            axes.setdefault(path, set()).add(axis % ndim)
+    return axes
 
-    Returns a pytree with the SAME structure/shapes as ``params`` (f32, 0/1),
-    with zeros on exactly the coordinates ``shrink_params`` would slice away:
-    the weight's filter axis AND every coupled tensor's coupled axis.
+
+def param_masks(params: Any, spec: PruneSpec, kept: Mapping[str, np.ndarray]) -> Any:
+    """Param-structured multiplicative keep-masks, in FACTORED form — the
+    static-shape dual of :func:`shrink_params`.
+
+    Returns a pytree with the structure of ``params`` (f32, 0/1) whose
+    leaves keep only the axes along which ``spec`` can zero them
+    (:func:`prunable_axes`); every other axis has size 1, so ``p * m``
+    broadcasts.  A conv kernel [kh, kw, c_in, c_out] whose filters and
+    inputs are both prunable gets a [1, 1, c_in, c_out] mask, its bias a
+    [c_out] one, a never-pruned leaf ``(1,) * ndim``.  The shapes depend
+    on ``spec`` alone, never on ``kept``, so all-ones masks (``kept={}``)
+    already have the shapes of any decision's.  Zeros sit on exactly the
+    coordinates ``shrink_params`` would slice away: the weight's filter
+    axis AND every coupled tensor's coupled axis.
 
     Because the zeroed set is closed under the layer coupling (the pruned
     filter's weights, its bias, and the next layer's matching input slices
@@ -374,7 +395,14 @@ def param_masks(params: Any, spec: PruneSpec, kept: Mapping[str, np.ndarray]) ->
     inside a compiled scan.  (GroupNorm/LayerNorm models normalize over the
     zeroed channels and therefore only approximate the shrunk model.)
     """
-    masks = jax.tree.map(lambda p: jnp.ones(p.shape, jnp.float32), params)
+    axes = prunable_axes(params, spec)
+
+    def ones(kp, p):
+        ax = axes.get(tuple(_norm_key(e) for e in kp), ())
+        return jnp.ones(tuple(d if i in ax else 1
+                              for i, d in enumerate(p.shape)), jnp.float32)
+
+    masks = jax.tree_util.tree_map_with_path(ones, params)
 
     def mask_axis(m: jnp.ndarray, axis: int, idx: np.ndarray) -> jnp.ndarray:
         d = m.shape[axis]
@@ -394,6 +422,41 @@ def param_masks(params: Any, spec: PruneSpec, kept: Mapping[str, np.ndarray]) ->
             masks = set_path(masks, c.path,
                              mask_axis(get_path(masks, c.path), c.axis, idx))
     return masks
+
+
+def factor_masks(masks: Any, like: Any) -> Any:
+    """Keep-masks reduced to the factored shapes of ``like`` (a tree of
+    the same structure: the mask slot, or its shapes).
+
+    A leaf that already has its slot's shape is returned as it is.  A
+    larger one — a full parameter-shaped mask from a checkpoint or a prune
+    artifact written before masks were factored — is cut to index 0 along
+    every axis its slot holds at size 1, after checking that it is constant
+    along those axes; otherwise it is a decision the factored slot cannot
+    hold, and :class:`~repro.core.plan.CheckpointError` names the leaf."""
+    from repro.core.plan import CheckpointError
+
+    def one(kp, m, ref):
+        target = tuple(ref.shape)
+        if tuple(m.shape) == target:
+            return m
+        name = "/".join(str(_norm_key(e)) for e in kp)
+        if len(m.shape) != len(target) or any(
+                t not in (1, d) for d, t in zip(m.shape, target)):
+            raise CheckpointError(
+                f"keep-mask {name!r} has shape {tuple(m.shape)}, which does "
+                f"not reduce to its factored shape {target}")
+        host = np.asarray(m)
+        head = host[tuple(slice(0, 1) if t == 1 else slice(None)
+                          for t in target)]
+        if not np.array_equal(np.broadcast_to(head, host.shape), host):
+            raise CheckpointError(
+                f"keep-mask {name!r} of shape {host.shape} varies along an "
+                f"axis its factored shape {target} holds at size 1: FedAP "
+                f"never zeroes along that axis, so this mask is refused")
+        return head
+
+    return jax.tree_util.tree_map_with_path(one, masks, like)
 
 
 def model_flops_fraction(params_before: Any, params_after: Any) -> float:

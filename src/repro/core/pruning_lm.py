@@ -151,22 +151,30 @@ def ffn_filter_masks(params: Any, kept: Any) -> dict:
 
 
 def ffn_param_masks(params: Any, kept: Any) -> Any:
-    """Param-structured 0/1 masks with zeros on exactly the coordinates
-    :func:`shrink_ffn_at` would slice away (wi/wg columns AND the coupled
-    wo rows) — the scanned-stack analogue of ``pruning.param_masks``.  The
+    """Param-structured 0/1 masks in FACTORED form, with zeros on exactly
+    the coordinates :func:`shrink_ffn_at` would slice away (wi/wg columns
+    AND the coupled wo rows) — the scanned-stack analogue of
+    ``pruning.param_masks``.  FedAP zeroes whole FFN units, so ``wi``/``wg``
+    get [L, 1, d_ff] masks, ``wo`` [L, d_ff, 1], and every other leaf
+    ``(1,) * ndim``: ``p * m`` broadcasts.  The shapes do not depend on
+    ``kept`` (all-ones masks already have a decision's shapes).  The
     zeroed set is closed under the FFN coupling, so the masked forward
     equals the shrunk forward exactly: a zero pre-activation unit
     contributes silu(0) = gelu(0) = 0 through wo."""
-    masks = jax.tree.map(lambda p: jnp.ones(p.shape, jnp.float32), params)
+    masks = jax.tree.map(lambda p: jnp.ones((1,) * p.ndim, jnp.float32),
+                         params)
+    if "mlp" not in params.get("layers", {}):
+        return masks        # no dense FFN stack: nothing FedAP can zero
     m = _unit_masks(params, kept)
     if m is None:
-        return masks
+        wi = params["layers"]["mlp"]["wi"]
+        m = np.ones((wi.shape[0], wi.shape[2]), np.float32)
     unit = jnp.asarray(m)                                              # [L, ff]
     mlp = dict(masks["layers"]["mlp"])
-    mlp["wi"] = mlp["wi"] * unit[:, None, :]
+    mlp["wi"] = unit[:, None, :]
     if "wg" in mlp:
-        mlp["wg"] = mlp["wg"] * unit[:, None, :]
-    mlp["wo"] = mlp["wo"] * unit[:, :, None]
+        mlp["wg"] = unit[:, None, :]
+    mlp["wo"] = unit[:, :, None]
     new_layers = dict(masks["layers"])
     new_layers["mlp"] = mlp
     masks = dict(masks)

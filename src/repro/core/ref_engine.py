@@ -17,7 +17,8 @@ mirrored here:
     the descent-consistent sign — see repro.core.momentum);
   * the static-shape masked mode (``cfg.use_masks``): params, gradients
     and momentum buffers are multiplied by the 0/1 keep-masks in
-    ``state["masks"]`` every round, exactly where the engine does;
+    ``state["masks"]`` every round, exactly where the engine does (NumPy
+    broadcasting: the masks may be factored, size 1 on unpruned axes);
   * the client-state algorithms: FedProx's proximal pull
     ``g + mu * (theta - anchor)`` inside each local step, and FedDyn's
     per-client correction in the engine's alpha-scaled parameterization
@@ -334,8 +335,8 @@ def ref_init_state(params: Any, cfg: EngineConfig, masks: Any = None,
         state["global_m"] = _zeros_like(params)
     if cfg.use_masks:
         state["masks"] = (tree_f64(masks) if masks is not None else
-                          jax.tree.map(lambda x: np.ones_like(
-                              np.asarray(x, np.float64)), params))
+                          jax.tree.map(lambda x: np.ones(
+                              (1,) * np.ndim(x), np.float64), params))
     if cfg.algorithm == "fedprox":
         state["client_state"] = {"per_client": {}, "shared": {}}
     elif cfg.algorithm == "feddyn":

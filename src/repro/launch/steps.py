@@ -24,8 +24,9 @@ wiring, and the (arch x shape) batch construction that
 State between rounds is just {global params, server momentum, [masks],
 round} — FL clients are stateless (the momentum restart is what makes
 this one program possible with zero extra communication).  With
-``use_masks`` the FedAP keep-masks ride in that state, sharded exactly
-like the params, so the prune round needs no re-lower of the mesh
+``use_masks`` the FedAP keep-masks ride in that state in factored form
+(only the axes FedAP prunes, every other axis at size 1; sharded like the
+params on those axes), so the prune round needs no re-lower of the mesh
 program (``with_masks`` injects a decision mid-run).
 
 This module is the POD-SCALE entry point — big-model (arch x shape) FL
@@ -82,9 +83,10 @@ class FLRunConfig:
     feddu: FedDUConfig = dataclasses.field(default_factory=FedDUConfig)
     use_server_update: bool = True
     use_momentum: bool = True
-    # Static-shape FedAP: keep-masks ride in the SPMD round state (sharded
-    # like the params — sharding/fl_specs.py is key-generic over the state
-    # dict), so the pod program prunes without a shape change or re-lower.
+    # Static-shape FedAP: factored keep-masks ride in the SPMD round state
+    # (sharded like the params on their prunable axes — see
+    # sharding/fl_specs.py), so the pod program prunes without a shape
+    # change or re-lower.
     use_masks: bool = False
     # "kernel" additionally threads filter-level masks (replicated, tiny)
     # into the model fns so masked dense layers run the differentiable
@@ -193,9 +195,14 @@ def make_fl_train_step(cfg: ModelConfig, run: FLRunConfig, num_clients: int,
     grad_fn, la_fn = build_model_fns(eng, loss_fn, la_base)
 
     def init_state(rng, filter_masks=None):
-        return init_round_state(model.init(rng), eng,
-                                filter_masks=filter_masks,
-                                num_clients=num_clients)
+        params = model.init(rng)
+        masks = None
+        if eng.use_masks:
+            from repro.core.backend import param_masks_for
+
+            masks = param_masks_for(model, params, {})
+        return init_round_state(params, eng, filter_masks=filter_masks,
+                                num_clients=num_clients, masks=masks)
 
     def train_step(state, batch):
         new_state, metrics = round_core(eng, grad_fn, la_fn, state, batch)
@@ -208,9 +215,13 @@ def with_masks(state: dict, masks: Any, filter_masks: Any = None) -> dict:
     """Inject FedAP keep-masks into a running masked round state — the pod
     analogue of the simulation executor's ``Prune(mode="mask")`` event:
     momentum restarts, params are masked, shapes (and the lowered mesh
-    program) are untouched.  ``filter_masks`` swaps the kernel-mode filter
-    masks too (required when the state carries a ``filter_masks`` slot —
-    its pytree structure must stay identical)."""
+    program) are untouched.  ``masks`` are the model's factored masks
+    (``backend.param_masks_for``); a full parameter-shaped mask is reduced
+    to the slot's factored shape if it is constant along the slot's size-1
+    axes, and refused with a ``CheckpointError`` naming the leaf if not.
+    ``filter_masks`` swaps the kernel-mode filter masks too (required when
+    the state carries a ``filter_masks`` slot — its pytree structure must
+    stay identical)."""
     from repro.core.backend import masked_round_state
 
     if "masks" not in state:

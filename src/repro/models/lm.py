@@ -434,8 +434,9 @@ class LM:
         return pruning_lm.ffn_filter_masks(params, kept)
 
     def param_masks(self, params, kept):
-        """Param-structured 0/1 masks (coupling-closed: wi/wg cols + wo
-        rows) for the static-shape masked round state."""
+        """Param-structured, factored 0/1 masks (coupling-closed: wi/wg
+        cols [L, 1, d_ff] + wo rows [L, d_ff, 1], every other leaf
+        ``(1,) * ndim``) for the static-shape masked round state."""
         from repro.core import pruning_lm
 
         return pruning_lm.ffn_param_masks(params, kept)

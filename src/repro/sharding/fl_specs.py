@@ -19,13 +19,16 @@ def _axis(axes: tuple):
 def fl_state_specs(state_shapes: Any, model_axes: Any, plan: MeshPlan, *,
                    client_axes: tuple = ()) -> Any:
     """Engine round state = {params, server_m, [global_m], [masks],
-    [filter_masks], [client_state], round}: every momentum buffer — and the
-    FedAP keep-masks of the static-shape masked mode
-    (``EngineConfig.use_masks``) — mirrors the params' model sharding
-    (TP/FSDP, replicated over client axes); the round counter is
-    replicated.  The kernel-mode ``filter_masks`` slot (per-layer [d_l]
-    vectors, a few KB) is fully replicated: every shard needs the whole
-    block mask to decide which MXU blocks to skip.  Key-generic so the
+    [filter_masks], [client_state], round}: every momentum buffer mirrors
+    the params' model sharding (TP/FSDP, replicated over client axes); the
+    round counter is replicated.  The FedAP keep-masks of the static-shape
+    masked mode (``EngineConfig.use_masks``) are FACTORED — each leaf holds
+    at size 1 every axis FedAP never prunes (``pruning.param_masks``) — so
+    each takes its parameter's spec with ``None`` on every size-1 axis: a
+    broadcast axis is never sharded, and a pruned axis shards as its
+    parameter does.  The kernel-mode ``filter_masks`` slot (per-layer
+    [d_l] vectors, a few KB) is fully replicated: every shard needs the
+    whole block mask to decide which MXU blocks to skip.  Key-generic so the
     communicated-momentum (FedDA) state and the mask slots shard without
     special-casing.
 
@@ -63,6 +66,12 @@ def fl_state_specs(state_shapes: Any, model_axes: Any, plan: MeshPlan, *,
                     "shared": shared_spec(v["shared"])}
         if k == "filter_masks" or model_axes is None:
             return jax.tree.map(lambda _: P(), v)
+        if k == "masks":
+            return jax.tree.map(
+                lambda m, spec: P(*(None if d == 1 else a for d, a in
+                                    zip(m.shape, tuple(spec)
+                                        + (None,) * len(m.shape)))),
+                v, param_specs(state_shapes["params"], model_axes, plan))
         return param_specs(v, model_axes, plan)
 
     return {k: one(k, v) for k, v in state_shapes.items()}

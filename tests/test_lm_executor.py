@@ -481,4 +481,5 @@ def test_lm_engine_matches_f64_oracle(oracle_world, row):
     if masks is not None:
         for leaf, m in zip(jax.tree.leaves(phist), jax.tree.leaves(masks)):
             np.testing.assert_array_equal(
-                np.asarray(leaf[-1])[np.asarray(m) == 0], 0.0)
+                np.asarray(leaf[-1])[
+                    np.broadcast_to(m, leaf.shape[1:]) == 0], 0.0)

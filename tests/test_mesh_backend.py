@@ -324,8 +324,8 @@ class TestMeshFullPlan:
         # masked coordinates are exactly zero through the post-prune rounds
         for p, m in zip(jax.tree.leaves(res_m.params),
                         jax.tree.leaves(res_m.state["masks"])):
-            np.testing.assert_array_equal(np.asarray(p)[np.asarray(m) == 0],
-                                          0.0)
+            np.testing.assert_array_equal(
+                np.asarray(p)[np.broadcast_to(m, p.shape) == 0], 0.0)
 
     def test_prune_applied_without_relowering(self, runs):
         """ONE chunk trace covers the whole plan: the mid-run mask
@@ -609,6 +609,18 @@ class ShardedDictModel:
         return softmax_xent_acc(self.apply(params, batch)[0],
                                 batch["labels"])[0]
 
+    def prune_spec(self, params):
+        """FedAP's seam: w1's hidden units, coupled to w2's rows — it sizes
+        the round state's factored mask slot (w1 [1, D_H], w2 [D_H, 1])."""
+        from repro.core.pruning import (
+            CoupledParam,
+            PrunableLayer,
+            PruneSpec,
+        )
+
+        return PruneSpec(layers=(PrunableLayer(
+            "hidden", ("w1",), 1, (CoupledParam(("w2",), 0),)),))
+
 
 class TestWithMasksShardedRoundTrip:
     def test_sharded_state_roundtrip_no_relower(self):
@@ -634,9 +646,15 @@ class TestWithMasksShardedRoundTrip:
         shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                                  is_leaf=lambda x: isinstance(x, P))
         state = jax.device_put(state, shardings)
-        # the hidden dim really shards when more than one device is present
+        # the hidden dim really shards when more than one device is present;
+        # the factored masks hold the never-pruned axes at size 1, and a
+        # size-1 axis is never sharded
+        assert state["masks"]["w1"].shape == (1, model.D_H)
+        assert state["masks"]["w2"].shape == (model.D_H, 1)
         if N_DEV > 1:
             assert state["params"]["w1"].sharding.spec == P(None, "model")
+            assert state["masks"]["w1"].sharding.spec == P(None, "model")
+            assert state["masks"]["w2"].sharding.spec == P("model", None)
 
         rng = np.random.default_rng(0)
         batch = {
@@ -655,7 +673,9 @@ class TestWithMasksShardedRoundTrip:
         state1, _ = compiled(state, batch)
 
         # inject a decision mid-run: mask half of w1's output filters (and
-        # w2's matching input rows — the coupled closure)
+        # w2's matching input rows — the coupled closure), given at the
+        # parameters' full shapes as an older prune artifact holds them:
+        # with_masks reduces them to the slot's factored shapes
         m = np.ones((model.D_H,), np.float32)
         m[model.D_H // 2:] = 0.0
         masks = {"w1": jnp.asarray(np.broadcast_to(m, (model.D_IN,

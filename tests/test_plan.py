@@ -301,7 +301,7 @@ class TestFedAPPlan:
         for p, m in zip(jax.tree.leaves(res_m.params),
                         jax.tree.leaves(res_m.state["masks"])):
             np.testing.assert_array_equal(
-                np.asarray(p)[np.asarray(m) == 0], 0.0)
+                np.asarray(p)[np.broadcast_to(m, p.shape) == 0], 0.0)
 
     def test_callback_after_masked_prune_keeps_masks(self, tiny_world):
         """A Callback replacing params after a Prune(mode='mask') must not
@@ -320,8 +320,8 @@ class TestFedAPPlan:
         for p, m in zip(jax.tree.leaves(res.params),
                         jax.tree.leaves(res.state["masks"])):
             np.testing.assert_array_equal(
-                np.asarray(p)[np.asarray(m) == 0], 0.0)
-            masked_coords += int(np.sum(np.asarray(m) == 0))
+                np.asarray(p)[np.broadcast_to(m, p.shape) == 0], 0.0)
+            masked_coords += int(np.sum(np.broadcast_to(m, p.shape) == 0))
         assert masked_coords > 0
 
     def test_shrink_records_params_before(self, pruned_runs):
@@ -690,7 +690,7 @@ class TestKernelPathInsideEngine:
         for p, m in zip(jax.tree.leaves(res_k.params),
                         jax.tree.leaves(res_k.state["masks"])):
             np.testing.assert_array_equal(
-                np.asarray(p)[np.asarray(m) == 0], 0.0)
+                np.asarray(p)[np.broadcast_to(m, p.shape) == 0], 0.0)
 
 
 class TestFedAPParticipantsClamp:

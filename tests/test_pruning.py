@@ -177,11 +177,11 @@ class TestShrink:
         keep = np.zeros(16)
         keep[kept["conv"]] = 1.0
         np.testing.assert_allclose(masks["conv"]["b"], keep)
+        # factored: only the pruned axis is held at full size
         np.testing.assert_allclose(masks["conv"]["w"],
-                                   np.broadcast_to(keep, (3, 3, 4, 16)))
-        np.testing.assert_allclose(
-            masks["next"]["w"],
-            np.broadcast_to(keep[None, None, :, None], (3, 3, 16, 8)))
+                                   keep.reshape(1, 1, 1, 16))
+        np.testing.assert_allclose(masks["next"]["w"],
+                                   keep.reshape(1, 1, 16, 1))
         # the dual invariant: shrinking the mask-multiplied params drops
         # only ones, shrinking the complement drops only zeros
         masked = jax.tree.map(lambda p, m: p * m, params, masks)
@@ -231,7 +231,9 @@ class TestPathAddressing:
         assert out[0].b.shape == (4,)
         assert out[1].w.shape == (3, 3, 4, 8)
         masks = param_masks(params, spec, kept)
-        assert masks[0].w.shape == (3, 3, 4, 16)
+        assert masks[0].w.shape == (1, 1, 1, 16)
+        assert masks[1].w.shape == (1, 1, 16, 1)
+        assert masks[1].b.shape == (1,)
         assert float(jnp.sum(masks[0].b)) == 4.0
 
 

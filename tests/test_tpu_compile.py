@@ -12,7 +12,9 @@ import every test file.  The persistent compile cache is off around these
 compiles, because an entry written for a described chip cannot be read
 back without one.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.masked_matmul import masked_matmul
+from repro.launch.hlo_cost import HloCostModel, _shape_dims
 
 # OLMo-1B (src/repro/configs/olmo_1b.py): d_model 2048, d_ff 8192,
 # 16 heads = 16 KV heads of head_dim 128.
@@ -156,3 +159,184 @@ def test_decode_attention_with_lengths_compiles(one_chip):
         _spec((slots, 1, KV_HEADS, HEAD_DIM), jnp.bfloat16, one_chip),
         kv, kv, _spec((slots,), jnp.int32, one_chip)).compile()
     assert _mosaic_calls(compiled) >= 1
+
+
+# ---------------------------------------------------------------------------
+# FedAP's keep-masks in the round state are factored: no compiled pass of
+# the round reads a parameter-sized mask
+# ---------------------------------------------------------------------------
+
+# Instructions that only route a value (no pass over its bytes).
+_PLUMBING = {"tuple", "get-tuple-element", "while", "call", "conditional",
+             "bitcast", "parameter", "opt-barrier"}
+
+
+def _both(a, b):
+    if isinstance(a, list) and isinstance(b, list):
+        return [_both(x, y) for x, y in zip(a, b)]
+    return a is True and b is True
+
+
+def _entry_params(text: str) -> list[tuple[str, str]]:
+    """(op_name, type) of every parameter of the compiled entry."""
+    cm = HloCostModel(text)
+    out = []
+    for ins in cm.comps[cm.entry]:
+        if ins.opcode == "parameter":
+            m = re.search(r'op_name="([^"]*)"', ins.line)
+            out.append((m.group(1).replace("\\'", "'") if m else "",
+                        ins.type_str))
+    return out
+
+
+def _mask_fed(text: str, full_numels: set) -> list[tuple]:
+    """Instructions that read a float32 value of a full parameter size
+    computed from the state's masks alone.
+
+    A value is mask-derived when every operand that is neither a scalar
+    nor a constant is: the entry parameters of ``state['masks']``, and what
+    broadcasts, slices, copies or fuses them, followed through tuples,
+    while loops (the body sees the loop's initial tuple) and calls.  Each
+    non-routing instruction that takes such a value of a size in
+    ``full_numels`` is reported as (computation, instruction, operand,
+    type)."""
+    cm = HloCostModel(text)
+    hits = set()
+
+    def analyze(comp, param_taint):
+        tab = {i.name: i for i in cm.comps[comp]}
+        taint, root = {}, None
+        for ins in cm.comps[comp]:
+            ops = [o for o in ins.operands if o in tab]
+            if ins.opcode == "parameter":
+                idx = int(re.search(r"parameter\((\d+)\)", ins.line).group(1))
+                t = param_taint(idx, ins)
+            elif ins.opcode == "get-tuple-element":
+                k = int(re.search(r"index=(\d+)", ins.line).group(1))
+                src = taint.get(ops[0])
+                t = src[k] if isinstance(src, list) else False
+            elif ins.opcode == "tuple":
+                t = [taint.get(o, False) for o in ops]
+            elif ins.opcode == "while":
+                body = re.search(r"body=%([\w.\-]+)", ins.line).group(1)
+                init = taint.get(ops[0], False)
+                t = _both(init, analyze(body, lambda i, _: init))
+            elif ins.opcode == "call":
+                f = re.search(r"to_apply=%([\w.\-]+)", ins.line).group(1)
+                t = analyze(f, lambda i, _: taint.get(ops[i], False))
+            else:
+                vals = []
+                for o in ops:
+                    dims = _shape_dims(tab[o].type_str)
+                    if tab[o].opcode in ("constant", "iota") or (
+                            len(dims) == 1 and math.prod(dims[0][1]) == 1):
+                        continue
+                    vals.append(taint.get(o, False))
+                    if (taint.get(o) is True and ins.opcode not in _PLUMBING
+                            and len(dims) == 1 and dims[0][0] == "f32"
+                            and math.prod(dims[0][1]) in full_numels):
+                        hits.add((comp, ins.name, o,
+                                  tab[o].type_str.split("{")[0]))
+                t = bool(vals) and all(v is True for v in vals)
+            taint[ins.name] = t
+            if ins.line.lstrip().startswith("ROOT"):
+                root = ins.name
+        return taint[root]
+
+    analyze(cm.entry, lambda i, ins: "state['masks']"
+            in ins.line.replace("\\'", "'"))
+    return sorted(hits)
+
+
+@pytest.mark.parametrize("mask_shape,expect_hit", [
+    ((D_MODEL, D_FF), True), ((1, D_FF), False)], ids=["full", "factored"])
+def test_mask_flow_check_sees_a_full_mask(one_chip, mask_shape, expect_hit):
+    """The check below on one masked update step: a parameter-shaped mask
+    operand is found, a factored one is not."""
+    def step(state):
+        p, m = state["params"]["w"], state["masks"]["w"]
+        return p - 0.1 * (p * p) * m
+
+    f32 = lambda shape: _spec(shape, jnp.float32, one_chip)
+    text = jax.jit(step).lower({"params": {"w": f32((D_MODEL, D_FF))},
+                                "masks": {"w": f32(mask_shape)}}
+                               ).compile().as_text()
+    assert bool(_mask_fed(text, {D_MODEL * D_FF})) == expect_hit
+
+
+@pytest.fixture(scope="module")
+def olmo_round(one_chip):
+    """One FedDUMAP round (``engine.round_core``) of a 1-layer LM at
+    OLMo-1B widths with float32 master weights: 2 clients of 2 local steps
+    of 2x512 tokens, tau 2, restart local momentum and server momentum,
+    FedAP masks on the masked-matmul kernel — compiled for the described
+    v5e.  Returns (optimized HLO text, the round state's shapes)."""
+    import dataclasses
+
+    import repro.kernels.ops as ops
+    from repro.configs.olmo_1b import CONFIG
+    from repro.core import engine
+    from repro.core.backend import model_fns, param_masks_for
+    from repro.models.lm import LM
+
+    model = LM(dataclasses.replace(CONFIG, num_layers=1,
+                                   param_dtype="float32"))
+    eng = engine.EngineConfig(lr=1e-2, use_server_update=True,
+                              local_momentum="restart",
+                              server_momentum=True, use_masks=True,
+                              masked_compute="kernel")
+    grad_fn, la_fn = model_fns(model, eng)
+    state = jax.eval_shape(
+        lambda p: engine.init_round_state(
+            p, eng, filter_masks=model.filter_masks(p, {}),
+            masks=param_masks_for(model, p, {})),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    state = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), state)
+    tok = lambda *lead: _spec(lead + (512,), jnp.int32, one_chip)
+    scalar = _spec((), jnp.float32, one_chip)
+    batch = {"client": (tok(2, 2, 2), tok(2, 2, 2)),
+             "sizes": _spec((2,), jnp.float32, one_chip),
+             "server": (tok(2, 2), tok(2, 2)),
+             "d_round": scalar, "d_server": scalar, "n0": scalar}
+    # the kernels pick interpret mode from the (CPU) default backend; the
+    # compile is for the chip
+    was = ops._interpret
+    ops._interpret = lambda: False
+    try:
+        # the argument names are what the entry parameters' op_name says
+        compiled = jax.jit(
+            lambda state, batch: engine.round_core(eng, grad_fn, la_fn,
+                                                   state, batch)
+        ).lower(state, batch).compile()
+    finally:
+        ops._interpret = was
+    return compiled.as_text(), state
+
+
+def _nbytes(type_str: str) -> int:
+    return sum(4 * math.prod(d) for _, d in _shape_dims(type_str))
+
+
+def test_round_mask_slot_is_under_a_percent_of_params(olmo_round):
+    text, state = olmo_round
+    params = _entry_params(text)
+    mask_b = sum(_nbytes(t) for n, t in params
+                 if n.startswith("state['masks']"))
+    param_b = sum(_nbytes(t) for n, t in params
+                  if n.startswith("state['params']"))
+    assert len([n for n, _ in params if n.startswith("state['masks']")]) \
+        == len(jax.tree.leaves(state["masks"]))
+    assert param_b > 500e6           # embed + one layer, float32
+    assert 0 < mask_b < 0.01 * param_b
+
+
+def test_round_reads_no_parameter_sized_mask(olmo_round):
+    """No fusion (or any other pass) of the compiled round takes a float32
+    operand of a full parameter size that comes from ``state['masks']``:
+    the factored masks stay broadcast inside the update fusions."""
+    text, state = olmo_round
+    full = set()
+    for p in jax.tree.leaves(state["params"]):
+        n = math.prod(p.shape)
+        full |= {n, 2 * n, n // p.shape[0]}      # + client dim, a layer
+    assert _mask_fed(text, full) == []
