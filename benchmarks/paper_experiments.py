@@ -101,8 +101,7 @@ def run_one(tag: str, *, model_name="cnn", algo="fedavg", p=0.05,
     feddu = FedDUConfig(**(feddu_overrides or {}),
                         **({"static_tau_eff": static_tau} if static_tau else {}))
     # Paper-faithful FedAP re-materializes the model (the device-FLOP shrink
-    # of Tables 6-9) -> Prune(mode="shrink"); the in-scan masked variant is
-    # benchmarked separately (perf_iter --fedap-plan).
+    # of Tables 6-9) -> Prune(mode="shrink").
     apcfg = FedAPConfig(prune_round=prune_round, probe_size=32, participants=6)
     plan = TrainPlan.standard(rounds, eval_every=2)
 

@@ -34,8 +34,8 @@ Two implementations ship:
       same way (padded rows corrected out exactly), and Prune events run
       POD-SIDE: `fedap.fedap_decision_sharded` gathers the probe/Fisher
       statistics from mesh-sharded participants (ragged probe sets padded
-      and masked), `launch.steps.with_masks` injects a mask decision into
-      the live state without re-lowering the mesh program, and a shrink
+      and masked), a mask decision goes into the live state through
+      `masked_round_state` without re-lowering the mesh program, and a shrink
       compacts the state SHARD-LOCALLY (one jitted gather of the kept
       filters — params and momentum never round-trip through the host).
 
@@ -79,9 +79,8 @@ from repro.core.plan import (
 def model_fns(model, eng: EngineConfig):
     """(grad_fn, loss_and_acc_fn) for `engine.round_core` from a simulation
     model (``loss_and_acc(params, x, y[, masks=])``).  The kernel/non-kernel
-    arity split lives in ``engine.build_model_fns``, shared with the pod
-    path (`launch.steps.make_fl_train_step`) — only the batch adaptation
-    ((x, y) tuples here, token dicts there) differs per caller.
+    arity split lives in ``engine.build_model_fns``; this adapts the
+    ``(x, y)`` batch tuples to it.
 
     Models without the ``masks=`` keyword (e.g. ad-hoc test models) are
     still valid outside kernel mode — the filter masks are only threaded
@@ -274,9 +273,8 @@ def masked_round_state(state: dict, masks: Any, filter_masks: Any = None
     """Inject FedAP keep-masks into a live masked round state: momentum
     (and the FedProx/FedDyn client_state corrections) restarts, params are
     masked, shapes and shardings — and therefore the compiled (or lowered
-    SPMD) program — are untouched.  The canonical
-    implementation behind both the executor's ``Prune(mode="mask")`` apply
-    and the pod path's :func:`repro.launch.steps.with_masks`.  Masks of a
+    SPMD) program — are untouched.  The one implementation of the
+    ``Prune(mode="mask")`` apply, on every backend.  Masks of a
     full parameter shape (an older artifact) are reduced to the slot's
     factored shapes first (``pruning.factor_masks``)."""
     from repro.core.pruning import factor_masks
@@ -500,17 +498,20 @@ class _EngineBackend:
         already-decided kept indices instead of restarting momentum, so
         masked-then-shrunk training continues exactly like
         shrink-from-the-start on normalization-free models."""
-        params = jax.tree.map(jnp.copy, state["params"])
-        round_ = state["round"]
-
         if mode == "mask":
-            masks = param_masks_for(self.model, params, kept)
-            fmasks = filter_masks_for(self.model, params, kept)
+            # the mask builders read only the params' shapes
+            masks = param_masks_for(self.model, state["params"], kept)
+            fmasks = filter_masks_for(self.model, state["params"], kept)
             new_state = masked_round_state(
                 state, masks,
                 filter_masks=fmasks if self._kernel_masks else None)
             return self._place_state(new_state), {"filter_masks": fmasks}
+        return self._shrink(state, kept, compact_existing=compact_existing)
 
+    def _shrink(self, state: dict, kept, *, compact_existing: bool):
+        """``Prune(mode="shrink")``: re-materialize eagerly, then place."""
+        params = jax.tree.map(jnp.copy, state["params"])
+        round_ = state["round"]
         new_params = shrink_params_for(self.model, params, kept)
         # kernel mode: all-ones filter masks at the SHRUNK shapes — the
         # compacted model has nothing left to skip
@@ -622,11 +623,11 @@ class MeshBackend(_EngineBackend):
       simulation models, which publish no model-sharding axes);
     * Prune events run pod-side: ``fedap.fedap_decision_sharded`` computes
       the probe/Fisher statistics on mesh-sharded participants, a mask
-      decision is injected through ``launch.steps.with_masks`` — the
-      chunk program is NOT re-lowered (mask mode keeps every shape, and
-      the carry structure was final from round 0) — and a SHRINK runs as
-      one jitted shard-local compaction (``NamedSharding`` outputs, no
-      host round-trip of params or momentum; see ``apply_prune``).
+      decision is injected by the base ``apply_prune`` — the chunk
+      program is NOT re-lowered (mask mode keeps every shape, and the
+      carry structure was final from round 0) — and a SHRINK runs as one
+      jitted shard-local compaction (``NamedSharding`` outputs, no host
+      round-trip of params or momentum; see ``_shrink``).
     """
 
     name = "mesh"
@@ -797,24 +798,7 @@ class MeshBackend(_EngineBackend):
             rng=np.random.default_rng(self.cfg.seed),
             mesh=self.mesh, client_axes=self.plan.client_axes)
 
-    @_span("fl.apply_prune")
-    def apply_prune(self, state, mode, kept, *, compact_existing=False):
-        if mode != "mask":
-            return self._sharded_shrink(state, kept,
-                                        compact_existing=compact_existing)
-        # mask mode: the pod-path injection helper — shapes, shardings and
-        # the lowered chunk program are untouched
-        from repro.launch.steps import with_masks
-
-        params = state["params"]
-        masks = param_masks_for(self.model, params, kept)
-        fmasks = filter_masks_for(self.model, params, kept)
-        new_state = with_masks(
-            state, masks,
-            filter_masks=fmasks if self._kernel_masks else None)
-        return self._place_state(new_state), {"filter_masks": fmasks}
-
-    def _sharded_shrink(self, state, kept, *, compact_existing):
+    def _shrink(self, state, kept, *, compact_existing):
         """``Prune(mode="shrink")`` without the host round-trip.
 
         The base-class shrink re-materializes eagerly (one dispatch per

@@ -4,11 +4,10 @@ round (steps 1-5 of Section 3.1), shared by every execution path.
 ``round_core`` is a pure function of (config, model fns, state, batch) and is
 safe under ``jit``, ``lax.scan``, ``vmap`` and ``shard_map``:
 
-  * the simulation driver (`repro.core.rounds.FederatedTrainer`) scans it
-    over rounds, with client selection and batch sampling done ON DEVICE
-    through `jax.random` keys threaded in the scan carry — no host sync;
-  * the pod-scale SPMD path (`repro.launch.steps.make_fl_train_step`) wraps
-    it once per mesh program and shard_maps it via `sharding/fl_specs.py`.
+  ``FederatedTrainer`` -> ``PlanExecutor`` -> backend (`repro.core.backend`)
+  is its one wiring: each backend scans it over rounds, with client
+  selection and batch sampling done ON DEVICE through `jax.random` keys
+  threaded in the scan carry — no host sync.
 
 Model access is abstracted to two callables over an opaque batch pytree:
 
@@ -281,10 +280,8 @@ def apply_masks(tree: Any, masks: Any) -> Any:
 
 def build_model_fns(cfg: EngineConfig, loss_fn: Callable,
                     la_fn: Callable) -> tuple[Callable, Callable]:
-    """The ONE place the kernel-mode model-fn arity is decided — shared by
-    the executor backends (``core.backend.model_fns``) and the pod path
-    (``launch.steps.make_fl_train_step``) so the 3-arg kernel signature
-    cannot drift between them.
+    """The ONE place the kernel-mode model-fn arity is decided, for the
+    executor backends (``core.backend.model_fns``).
 
     Callers adapt their model to two mask-aware callables over an opaque
     batch:

@@ -34,7 +34,8 @@ substrate::
     dimension and the sampled round batch shard over the mesh client axes
     (`sharding/fl_specs.py`), the FedAvg reduction becomes per-shard
     partial sums + one all-reduce, and ``Prune`` events run pod-side
-    (`fedap_decision_sharded` + `launch.steps.with_masks`, no re-lower);
+    (`fedap_decision_sharded` + `backend.masked_round_state`, no
+    re-lower);
   * both backends double-buffer the in-scan sampling by default
     (``FLConfig.prefetch_sampling``): round t+1's gather is issued while
     round t computes, with a bit-identical key chain and batch sequence;
@@ -212,8 +213,7 @@ def feddumap_config(**kw) -> FLConfig:
 
 
 def engine_config(cfg: FLConfig) -> EngineConfig:
-    """The FLConfig -> EngineConfig wiring (locked against the pod path's
-    FLRunConfig wiring by tests/test_engine_diff.py)."""
+    """The FLConfig -> EngineConfig wiring (the round's only one)."""
     return EngineConfig(
         lr=cfg.lr, lr_decay=cfg.lr_decay,
         use_server_update=cfg.use_server_update,

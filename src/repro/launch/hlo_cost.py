@@ -94,14 +94,6 @@ class CostTotals:
         for k, v in other.collective_counts.items():
             self.collective_counts[k] = self.collective_counts.get(k, 0) + v * mult
 
-    @property
-    def wire_bytes(self) -> float:
-        # ring factors on a 16-way axis (documented in hlo_analysis)
-        f = {"all-reduce": 2 * 15 / 16, "all-gather": 15 / 16,
-             "reduce-scatter": 15 / 16, "all-to-all": 15 / 16,
-             "collective-permute": 1.0}
-        return sum(v * f.get(k, 1.0) for k, v in self.collective_bytes.items())
-
 
 class HloCostModel:
     def __init__(self, hlo_text: str):

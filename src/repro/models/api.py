@@ -1,8 +1,8 @@
 """Public model-building API + input specs for every (arch x shape).
 
 ``input_specs`` is the single source of truth for what each input shape
-means per family — used by smoke tests (concrete arrays) and by the
-multi-pod dry-run (ShapeDtypeStruct stand-ins, no allocation).
+means per family — concrete arrays for smoke tests, ShapeDtypeStruct
+stand-ins (no allocation) for compile rehearsals.
 """
 from __future__ import annotations
 
@@ -36,7 +36,7 @@ def input_specs(cfg: ModelConfig, shape: InputShape, *, abstract: bool = True,
                 seed: int = 0) -> dict[str, Any]:
     """Batch pytree for (cfg, shape).
 
-    abstract=True  -> jax.ShapeDtypeStruct leaves (dry-run lowering)
+    abstract=True  -> jax.ShapeDtypeStruct leaves (lowering only)
     abstract=False -> concrete random arrays (smoke tests / benchmarks)
     """
     b, s = shape.global_batch, shape.seq_len

@@ -8,7 +8,7 @@ Conventions
   them to mesh axes (TP over 'model', optional FSDP over 'data').
 * Compute dtype = input dtype (bf16 on TPU); numerics-critical reductions
   (softmax, norms, rope, SSM state) run in f32.
-* ``attn_impl``: 'xla' (jnp reference; what the dry-run lowers) or
+* ``attn_impl``: 'xla' (jnp reference) or
   'pallas' (the kernels in repro.kernels; validated in interpret mode).
 """
 from __future__ import annotations

@@ -18,8 +18,7 @@ One class (:class:`LM`) covers the six families:
 
 Deep homogeneous stacks (dense/moe/vlm/hybrid decoders) are executed with
 ``lax.scan`` over stacked layer parameters (+ optional per-block remat), so
-HLO size is O(1) in depth — required for the 512-device AOT dry-runs and
-the production-standard choice.
+HLO size is O(1) in depth — the production-standard choice.
 
 Batches are dicts:
   tokens [B,S] int32          (dense/moe/encdec-decoder input)
@@ -39,7 +38,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.sharding.ctx import constrain_batch
 
 
 def _dtype(cfg: ModelConfig):
@@ -316,7 +314,7 @@ class LM:
                     f"{cfg.family!r}")
         if window == "auto":
             window = None            # training/prefill default: full attention
-        x = constrain_batch(self._embed_in(params, batch))
+        x = self._embed_in(params, batch)
         bsz, seq = x.shape[0], x.shape[1]
         pos = self._positions(batch, seq, bsz)
         aux = jnp.zeros((), jnp.float32)
@@ -330,7 +328,6 @@ class LM:
                 v = jnp.einsum("bsd,dhk->bshk", enc, blk["xattn"]["wv"])
                 x, _ = apply_block(blk, x, pos, cfg, {}, window=window,
                                    attn_impl=self.attn_impl, cross_kv=(k, v))
-                x = constrain_batch(x)
             return self._head(params, x), aux
 
         if cfg.family == "ssm":
@@ -341,7 +338,6 @@ class LM:
                     x = x + L.apply_slstm(blk["cell"], h, self._meta["slstm"], cfg)
                 else:
                     x = x + L.apply_mlstm(blk["cell"], h, self._meta["mlstm"], cfg)
-                x = constrain_batch(x)
             return self._head(params, x), aux
 
         # scanned stacks (filter masks, when given, ride the scan as extra
@@ -353,7 +349,6 @@ class LM:
             x, a = apply_block(layer_params, x, pos, cfg, self._meta,
                                window=window, attn_impl=self.attn_impl,
                                masks=layer_masks)
-            x = constrain_batch(x)
             return (x, aux + a), None
 
         if cfg.remat == "block":
@@ -375,7 +370,6 @@ class LM:
                 x = x + L.attention_block(shared["attn"], h, pos, cfg,
                                           window=cfg.sliding_window,
                                           attn_impl=self.attn_impl)
-                x = constrain_batch(x)
                 group = jax.tree.map(lambda p: p[a:b], params["layers"])
                 if masks is not None:
                     group = (group, jax.tree.map(lambda m: m[a:b], masks))
@@ -405,15 +399,16 @@ class LM:
         ``PaperModel.loss_and_acc``): positional ``(x, y)`` = (tokens
         [B,S], labels [B,S]) int32 arrays -> (loss, token accuracy).
 
-        Implemented via the pod adapter (:func:`launch.steps.
-        loss_and_accuracy`), so the executor backends and the pod step
-        share ONE loss/accuracy definition — the seam that lets
+        One forward gives both, so the Formula-7 accuracy gate rides the
+        first FedDU server gradient step.  This is the seam that lets
         ``FederatedTrainer``/``PlanExecutor`` drive transformer
         fine-tuning with the same code path as the CNN repro."""
-        from repro.launch.steps import loss_and_accuracy
-
-        return loss_and_accuracy(self, params, {"tokens": x, "labels": y},
+        logits, aux = self.apply(params, {"tokens": x, "labels": y},
                                  masks=masks)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        nll = -jnp.take_along_axis(logp, y[..., None], axis=-1)[..., 0]
+        ok = (jnp.argmax(logits, -1) == y).astype(jnp.float32)
+        return jnp.sum(nll) / nll.size + aux, jnp.mean(ok)
 
     # -- FedAP seam (executor Prune events; see repro.core.backend) ----------
     def decide_kept(self, params, p_star, *, align=128):
@@ -538,7 +533,7 @@ class LM:
                 raise ValueError(
                     f"masks= requires a scanned stack, not family "
                     f"{cfg.family!r}")
-        x = constrain_batch(self._embed_in(params, batch))
+        x = self._embed_in(params, batch)
         bsz = x.shape[0]
         idx = cache["index"]
         step_off = idx if jnp.ndim(idx) == 0 else idx[None, :, None]

@@ -2,8 +2,6 @@ from repro.sharding.specs import (
     MeshPlan,
     make_plan,
     param_specs,
-    batch_specs,
-    cache_specs,
 )
 
-__all__ = ["MeshPlan", "make_plan", "param_specs", "batch_specs", "cache_specs"]
+__all__ = ["MeshPlan", "make_plan", "param_specs"]

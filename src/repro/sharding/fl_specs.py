@@ -1,4 +1,4 @@
-"""PartitionSpecs for the FL train step's state and batch pytrees."""
+"""PartitionSpecs for the mesh backend's round state and round batch."""
 from __future__ import annotations
 
 from typing import Any
@@ -6,7 +6,6 @@ from typing import Any
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ModelConfig
 from repro.sharding.specs import MeshPlan, param_specs
 
 
@@ -139,69 +138,3 @@ def fl_sim_batch_specs(clients_per_round: int, plan: MeshPlan, *,
         # dropout indicator [C], alongside the client dim like "sizes"
         specs["active"] = cspec
     return specs
-
-
-def fl_batch_partition_specs(batch_shapes: Any, plan: MeshPlan) -> Any:
-    """batch = {client, server, sizes, d_round, d_server, n0}.
-
-    client leaves  [C, steps, b_c, ...]: C over client axes, b_c over the
-                   within-client batch axes (pod-silo archs).
-    server leaves  [tau, b, ...]: b over every non-model axis (the server
-                   update is data-parallel across the whole mesh).
-    """
-    ca = _axis(plan.client_axes)
-    ba = _axis(plan.batch_axes)
-    server_axes = plan.client_axes + plan.batch_axes
-    sa = _axis(server_axes)
-
-    def one_client(leaf, bdim):
-        # client leaves: [C, steps, b_c, ...]; positions: [C, steps, P, b_c, S]
-        nd = len(leaf.shape)
-        parts = [None] * nd
-        if plan.client_axes and leaf.shape[0] % plan.axis_size(plan.client_axes) == 0:
-            parts[0] = ca
-        if plan.batch_axes and nd > bdim and \
-                leaf.shape[bdim] % plan.axis_size(plan.batch_axes) == 0:
-            parts[bdim] = ba
-        return P(*parts)
-
-    def one_server(leaf, bdim=1):
-        # server leaves: [tau, B, ...]; positions: [tau, P, B, S]
-        nd = len(leaf.shape)
-        parts = [None] * nd
-        if nd > bdim and server_axes and \
-                leaf.shape[bdim] % plan.axis_size(server_axes) == 0:
-            parts[bdim] = sa
-        return P(*parts)
-
-    out = {
-        "client": {k: one_client(v, 3 if k == "positions" else 2)
-                   for k, v in batch_shapes["client"].items()},
-        "server": {k: one_server(v, 2 if k == "positions" else 1)
-                   for k, v in batch_shapes["server"].items()},
-        "sizes": P(),
-        "d_round": P(),
-        "d_server": P(),
-        "n0": P(),
-    }
-    for k in ("sel", "active"):
-        if k in batch_shapes:
-            out[k] = P()
-    return out
-
-
-def serve_batch_specs(batch_shapes: dict, plan: MeshPlan) -> dict:
-    """Inference batches: batch dim over every non-model axis.
-    Key-aware: 'positions' is [P, B, S] (batch at dim 1); all other leaves
-    carry batch at dim 0."""
-    axes = plan.client_axes + plan.batch_axes
-    a = _axis(axes)
-
-    def one(leaf, bdim):
-        nd = len(leaf.shape)
-        parts = [None] * nd
-        if axes and nd > bdim and leaf.shape[bdim] % plan.axis_size(axes) == 0:
-            parts[bdim] = a
-        return P(*parts)
-
-    return {k: one(v, 1 if k == "positions" else 0) for k, v in batch_shapes.items()}

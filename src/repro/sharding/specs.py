@@ -1,7 +1,7 @@
 """Logical-axis -> mesh PartitionSpec rules.
 
 The mesh is (data=16, model=16) single-pod or (pod=2, data=16, model=16)
-multi-pod (see repro.launch.mesh).  FL semantics determine the *client*
+multi-pod.  FL semantics determine the *client*
 axis (DESIGN.md Section 4):
 
   * data-client archs (<= ~10B): clients live on 'data' (x 'pod' when
@@ -18,11 +18,10 @@ non-divisible dim falls back to replication (e.g. whisper's 12 heads on a
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Any, Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 
@@ -129,74 +128,3 @@ def param_specs(shapes: Any, axes: Any, plan: MeshPlan,
     specs = [_spec_for(s.shape, ax, plan, client_leading=client_leading)
              for s, ax in zip(s_leaves, a_leaves)]
     return jax.tree.unflatten(s_def, specs)
-
-
-def _tree_spec(tree: Any, fn) -> Any:
-    return jax.tree.map(fn, tree)
-
-
-def batch_specs(batch: Any, plan: MeshPlan, *, client_leading: bool = False) -> Any:
-    """Shard the batch: leading client dim over client axes (if present),
-    then the batch dim over batch_axes; everything else replicated."""
-    def one(leaf):
-        shp = leaf.shape
-        parts: list = []
-        i = 0
-        if client_leading:
-            ca = plan.client_axes
-            ok = ca and shp[0] % plan.axis_size(ca) == 0
-            parts.append((ca if len(ca) > 1 else ca[0]) if ok else None)
-            i = 1
-            # [C, steps, b, ...]: steps unsharded
-            if len(shp) > 1:
-                parts.append(None)
-                i = 2
-        ba = plan.batch_axes
-        if i < len(shp) and ba and shp[i] % plan.axis_size(ba) == 0:
-            parts.append(ba if len(ba) > 1 else ba[0])
-            i += 1
-        while i < len(shp):
-            parts.append(None)
-            i += 1
-        return P(*parts[: len(shp)])
-
-    # positions [P,B,S] have batch at dim 1 — handled specially by caller if
-    # needed; here dim-0 heuristics suffice for dry-run coherence.
-    return _tree_spec(batch, one)
-
-
-def cache_specs(cache_shapes: Any, plan: MeshPlan, cfg: ModelConfig) -> Any:
-    """KV caches: [L, B, S, KV, hd] -> batch over batch_axes (+client axes
-    merged during inference), kv heads over model when divisible; SSM
-    states analogous."""
-    all_batch = tuple(a for a in (plan.client_axes + plan.batch_axes))
-
-    def one(leaf):
-        shp = leaf.shape
-        nd = len(shp)
-        parts = [None] * nd
-        kvh = cfg.padded_num_kv_heads
-
-        def fits(dim, axes):
-            return axes and dim % plan.axis_size(axes) == 0
-
-        if nd == 5:        # [L, B, S, KV, hd]
-            if fits(shp[1], all_batch):
-                parts[1] = all_batch if len(all_batch) > 1 else all_batch[0]
-            if shp[3] == kvh and fits(shp[3], plan.tp_axes):
-                parts[3] = plan.tp_axes[0]
-        elif nd == 4:      # [B, S, KV, hd] or [L, B, ...] ssm
-            if fits(shp[0], all_batch):
-                parts[0] = all_batch if len(all_batch) > 1 else all_batch[0]
-            elif fits(shp[1], all_batch):
-                parts[1] = all_batch if len(all_batch) > 1 else all_batch[0]
-            if shp[2] == kvh and fits(shp[2], plan.tp_axes):
-                parts[2] = plan.tp_axes[0]
-        elif nd >= 1:
-            if fits(shp[0], all_batch):
-                parts[0] = all_batch if len(all_batch) > 1 else all_batch[0]
-            elif nd > 1 and fits(shp[1], all_batch):
-                parts[1] = all_batch if len(all_batch) > 1 else all_batch[0]
-        return P(*parts)
-
-    return _tree_spec(cache_shapes, one)

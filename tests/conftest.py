@@ -6,8 +6,7 @@ import sys
 # from the repo root (tests usually run with only PYTHONPATH=src).
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# Keep tests on the single real device (the dry-run sets 512 host devices
-# ONLY inside repro.launch.dryrun, never here).
+# Keep tests on the CPU.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # The property-based tests import `hypothesis`; the container may not ship

@@ -437,3 +437,41 @@ ENTRY %main (p0: f32[4]) -> f32[4] {
         assert hlo_lint.host_callbacks(txt) == []
         cm = hlo_cost.HloCostModel(txt)
         assert dict(cm.entry_cost().collective_counts) == {}
+
+
+# ---------------------------------------------------------------------------
+# Layering: the model, kernel and round layers never reach up into launch/
+# ---------------------------------------------------------------------------
+
+def _imported_modules(path, package):
+    """Every module an ``import`` or ``from ... import`` in ``path`` names
+    (``from a import b`` yields ``a.b``, which may name an attribute of
+    ``a``; relative imports resolved against ``package``)."""
+    import ast
+
+    for node in ast.walk(ast.parse(path.read_text(), str(path))):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = node.module or ""
+            if node.level:
+                parts = package.split(".")
+                parent = parts[:len(parts) + 1 - node.level]
+                base = ".".join(parent + ([base] if base else []))
+            yield from (f"{base}.{a.name}" for a in node.names)
+
+
+def test_lower_layers_do_not_import_launch():
+    import pathlib
+
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    bad = []
+    for layer in ("models", "kernels", "core"):
+        for path in sorted((src / "repro" / layer).rglob("*.py")):
+            package = ".".join(path.relative_to(src).parent.parts)
+            for mod in _imported_modules(path, package):
+                if (mod == "repro.launch" or mod.startswith("repro.launch.")) \
+                        and not (mod == "repro.launch.mesh"
+                                 or mod.startswith("repro.launch.mesh.")):
+                    bad.append(f"{path.relative_to(src)}: {mod}")
+    assert bad == [], bad

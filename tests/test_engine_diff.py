@@ -7,7 +7,7 @@ with the FedDU / FedDUM / FedDA wirings, plus dropout rows — runs for
 several rounds through THREE legs
 
   * `repro.core.engine.round_core` under `jax.lax.scan` + `jit` (exactly
-    how the simulation driver and the pod path execute it),
+    how the plan executor's backends execute it),
   * the SAME scan with the round state placed on a host mesh through
     ``fl_specs.fl_state_specs`` NamedShardings (the MeshBackend's state
     placement, client_state per-client leaves included), and
@@ -17,10 +17,7 @@ on identical explicit batches, and every leg must agree with the oracle to
 <= 1e-5 PER ROUND through one shared assertion helper — a new engine mode
 gets locked by adding one table row.
 
-A second suite locks the two public wirings to each other: the pod path's
-``make_fl_train_step`` (FLRunConfig) and the simulation trainer's
-``round_step`` (FLConfig) must produce IDENTICAL params from the same
-params/batches on a toy model.  Limit tests pin the client algorithms'
+Limit tests pin the client algorithms'
 exact reductions: FedProx mu=0 is BIT-identical to FedAvg, FedDyn alpha=0
 matches FedAvg <= 1e-6.
 """
@@ -407,114 +404,3 @@ def test_closed_form_gradient_matches_jax_grad(world):
     g_jax = jnp_grad(jax.tree.map(jnp.asarray, params), b)
     for k in g_np:
         np.testing.assert_allclose(np.asarray(g_jax[k]), g_np[k], atol=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# Pod path vs. simulation path: the two public wirings of the one engine
-# ---------------------------------------------------------------------------
-
-class DictSoftmaxModel:
-    """Batch-dict adapter (the pod path's model interface) for the toy."""
-
-    def __init__(self, seed=7):
-        self._np = SoftmaxRegression(dim=DIM, num_classes=CLASSES)
-        self._seed = seed
-
-    def init(self, rng):
-        return jax.tree.map(jnp.asarray, self._np.init(seed=self._seed))
-
-    def apply(self, params, batch):
-        return batch["x"] @ params["w"] + params["b"], jnp.zeros(())
-
-    def loss(self, params, batch):
-        return softmax_xent_acc(self.apply(params, batch)[0],
-                                batch["labels"])[0]
-
-
-class XYSoftmaxModel:
-    """(params, x, y) adapter (the simulation trainer's model interface)."""
-
-    def __init__(self, seed=7):
-        self._np = SoftmaxRegression(dim=DIM, num_classes=CLASSES)
-        self._seed = seed
-
-    def init(self, rng):
-        return jax.tree.map(jnp.asarray, self._np.init(seed=self._seed))
-
-    def loss_and_acc(self, params, x, y):
-        return softmax_xent_acc(x @ params["w"] + params["b"], y)
-
-
-def test_pod_step_matches_simulation_step(world):
-    """make_fl_train_step (FLRunConfig wiring) and FederatedTrainer.round_step
-    (FLConfig wiring) must produce identical params from identical inputs."""
-    from repro.core.rounds import FederatedTrainer, FLConfig
-    from repro.data.pipeline import FederatedData
-    from repro.launch.steps import FLRunConfig, make_fl_train_step
-
-    _, params, rounds = world
-    lr = 0.08
-
-    # pod path
-    run_cfg = FLRunConfig(lr=lr, beta_local=0.9, beta_server=0.9,
-                          eta_server=1.0, local_steps=STEPS, server_tau=TAU,
-                          server_batch=SBATCH, use_server_update=True,
-                          use_momentum=True)
-    init_state, train_step = make_fl_train_step(
-        None, run_cfg, CLIENTS, model=DictSoftmaxModel())
-    pod_state = init_state(jax.random.key(0))
-    pod_taus = []
-    for b in rounds:
-        pod_batch = {
-            "client": {"x": jnp.asarray(b["client"][0]),
-                       "labels": jnp.asarray(b["client"][1])},
-            "server": {"x": jnp.asarray(b["server"][0]),
-                       "labels": jnp.asarray(b["server"][1])},
-            "sizes": jnp.asarray(b["sizes"]),
-            "d_round": jnp.asarray(b["d_round"]),
-            "d_server": jnp.asarray(b["d_server"]),
-            "n0": jnp.asarray(b["n0"]),
-        }
-        pod_state, t_eff = jax.jit(train_step)(pod_state, pod_batch)
-        pod_taus.append(float(t_eff))
-
-    # simulation path: same algorithm through FLConfig + round_step
-    model = XYSoftmaxModel()
-    n_k = STEPS * BATCH  # one local epoch of STEPS batches
-    data = FederatedData(
-        client_x=np.zeros((CLIENTS, n_k, DIM), np.float32),
-        client_y=np.zeros((CLIENTS, n_k), np.int64),
-        sizes=np.asarray([40.0, 25.0, 35.0], np.float32),
-        client_dists=np.full((CLIENTS, CLASSES), 0.25, np.float32),
-        server_x=np.zeros((TAU * SBATCH, DIM), np.float32),
-        server_y=np.zeros((TAU * SBATCH,), np.int64),
-        server_dist=np.full((CLASSES,), 0.25, np.float32),
-        test_x=np.zeros((4, DIM), np.float32),
-        test_y=np.zeros((4,), np.int64))
-    fl_cfg = FLConfig(num_clients=CLIENTS, clients_per_round=CLIENTS,
-                      local_epochs=1, batch_size=BATCH, lr=lr, lr_decay=1.0,
-                      use_server_update=True, local_momentum="restart",
-                      server_momentum=True, server_epochs=1,
-                      server_batch_size=SBATCH)
-    trainer = FederatedTrainer(model, data, fl_cfg)
-    sim_state = engine.init_round_state(model.init(None),
-                                        trainer.engine_config)
-    sim_taus = []
-    for b in rounds:
-        sim_batch = {
-            "client": (jnp.asarray(b["client"][0]),
-                       jnp.asarray(b["client"][1])),
-            "server": (jnp.asarray(b["server"][0]),
-                       jnp.asarray(b["server"][1])),
-            "sizes": jnp.asarray(b["sizes"]),
-            "d_round": jnp.asarray(b["d_round"]),
-            "d_server": jnp.asarray(b["d_server"]),
-            "n0": jnp.asarray(b["n0"]),
-        }
-        sim_state, metrics = trainer.round_step(sim_state, sim_batch)
-        sim_taus.append(float(metrics["tau_eff"]))
-
-    for a, b_ in zip(jax.tree.leaves(pod_state["params"]),
-                     jax.tree.leaves(sim_state["params"])):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-6)
-    np.testing.assert_allclose(pod_taus, sim_taus, rtol=1e-5)

@@ -4,8 +4,9 @@ The client-sharded execution backend must be NUMERICALLY the local scan
 backend: per-round parity local == mesh == f64 oracle (<= 1e-5), a full
 TrainPlan (Scan/Eval/Prune(mode="mask")/Snapshot/Callback) with the FedAP
 decision computed POD-SIDE and applied mid-run without re-lowering the
-chunk program, and `launch.steps.with_masks` round-tripping a genuinely
-sharded SPMD round state with shardings and the compiled program intact.
+chunk program, and `backend.masked_round_state` round-tripping a
+genuinely sharded SPMD round state with shardings and the compiled program
+intact.
 
 Multi-device intent: run under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the CI
@@ -329,7 +330,8 @@ class TestMeshFullPlan:
 
     def test_prune_applied_without_relowering(self, runs):
         """ONE chunk trace covers the whole plan: the mid-run mask
-        injection (steps.with_masks) must not re-lower the mesh program."""
+        injection (backend.masked_round_state) must not re-lower the mesh
+        program."""
         tr_m, res_m, _ = runs
         be = tr_m.backend(use_masks=True)
         # budgeted in repro/analysis/compile_budget.json: the mask-mode
@@ -587,12 +589,12 @@ class TestShardedShrink:
 
 
 # ---------------------------------------------------------------------------
-# with_masks on a GENUINELY sharded SPMD round state: shardings and the
-# compiled program survive the injection (satellite: sharded round-trip)
+# Mask injection into a GENUINELY sharded SPMD round state: shardings and
+# the compiled program survive it
 # ---------------------------------------------------------------------------
 
 class ShardedDictModel:
-    """Pod-interface toy whose hidden dim shards over the 'model' axis."""
+    """Toy whose hidden dim shards over the 'model' axis."""
 
     D_IN, D_H, D_OUT = 6, 2 * max(1, N_DEV), 4
 
@@ -601,13 +603,9 @@ class ShardedDictModel:
         return {"w1": jax.random.normal(k1, (self.D_IN, self.D_H)) * 0.3,
                 "w2": jax.random.normal(k2, (self.D_H, self.D_OUT)) * 0.3}
 
-    def apply(self, params, batch):
+    def loss_and_acc(self, params, batch):
         h = jax.nn.relu(batch["x"] @ params["w1"])
-        return h @ params["w2"], jnp.zeros(())
-
-    def loss(self, params, batch):
-        return softmax_xent_acc(self.apply(params, batch)[0],
-                                batch["labels"])[0]
+        return softmax_xent_acc(h @ params["w2"], batch["labels"])
 
     def prune_spec(self, params):
         """FedAP's seam: w1's hidden units, coupled to w2's rows — it sizes
@@ -624,8 +622,8 @@ class ShardedDictModel:
 
 class TestWithMasksShardedRoundTrip:
     def test_sharded_state_roundtrip_no_relower(self):
-        from repro.launch.steps import FLRunConfig, make_fl_train_step, \
-            with_masks
+        from repro.core.backend import masked_round_state, param_masks_for
+        from repro.core.momentum import FedDUMConfig
         from repro.sharding.fl_specs import fl_state_specs
         from repro.sharding.specs import MeshPlan
 
@@ -637,10 +635,18 @@ class TestWithMasksShardedRoundTrip:
                         fsdp_axes=(), tp_axes=("model",), batch_axes=("data",),
                         num_clients=1)
         model = ShardedDictModel()
-        run = FLRunConfig(lr=0.05, local_steps=2, server_tau=2,
-                          server_batch=4, use_masks=True)
-        init_state, train_step = make_fl_train_step(None, run, 3, model=model)
-        state = init_state(jax.random.key(0))
+        eng = engine.EngineConfig(
+            lr=0.05, use_server_update=True, local_momentum="restart",
+            server_momentum=True, use_masks=True,
+            feddum=FedDUMConfig(beta_server=0.9, beta_local=0.9,
+                                eta_server=1.0))
+        grad_fn, la_fn = engine.build_model_fns(
+            eng, lambda p, b, fm: model.loss_and_acc(p, b)[0],
+            lambda p, b, fm: model.loss_and_acc(p, b))
+        params = model.init(jax.random.key(0))
+        state = engine.init_round_state(
+            params, eng, num_clients=3,
+            masks=param_masks_for(model, params, {}))
         axes = {"w1": ("embed", "mlp"), "w2": ("mlp", "vocab_small")}
         specs = fl_state_specs(state, axes, plan)
         shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
@@ -668,14 +674,15 @@ class TestWithMasksShardedRoundTrip:
             "d_round": jnp.float32(0.3), "d_server": jnp.float32(0.02),
             "n0": jnp.float32(100.0),
         }
-        step = jax.jit(train_step)
+        step = jax.jit(lambda st, b: engine.round_core(eng, grad_fn, la_fn,
+                                                       st, b))
         compiled = step.lower(state, batch).compile()
         state1, _ = compiled(state, batch)
 
         # inject a decision mid-run: mask half of w1's output filters (and
         # w2's matching input rows — the coupled closure), given at the
         # parameters' full shapes as an older prune artifact holds them:
-        # with_masks reduces them to the slot's factored shapes
+        # masked_round_state reduces them to the slot's factored shapes
         m = np.ones((model.D_H,), np.float32)
         m[model.D_H // 2:] = 0.0
         masks = {"w1": jnp.asarray(np.broadcast_to(m, (model.D_IN,
@@ -683,7 +690,7 @@ class TestWithMasksShardedRoundTrip:
                  "w2": jnp.asarray(np.broadcast_to(m[:, None],
                                                    (model.D_H,
                                                     model.D_OUT)).copy())}
-        state2 = with_masks(state1, masks)
+        state2 = masked_round_state(state1, masks)
 
         # shardings unchanged leaf-for-leaf
         flat1 = jax.tree_util.tree_leaves_with_path(state1)
@@ -700,8 +707,8 @@ class TestWithMasksShardedRoundTrip:
 
         # the PRE-PRUNE compiled executable keeps running on the new state:
         # no re-lower, and the masked coordinates stay zero
-        state3, tau = compiled(state2, batch)
+        state3, mets = compiled(state2, batch)
         assert step._cache_size() <= 1
         np.testing.assert_array_equal(
             np.asarray(state3["params"]["w1"])[:, model.D_H // 2:], 0.0)
-        assert np.isfinite(float(tau))
+        assert np.isfinite(float(mets["tau_eff"]))
